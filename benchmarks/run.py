@@ -30,6 +30,8 @@ def main() -> None:
                          "fig8_kd_accuracy.DEFAULT_STEPS)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.common import artifact_path
     from benchmarks import (fig8_kd_accuracy, kernel_bench, ops_dispatch,
                             serve_throughput, table1_resources,

@@ -109,16 +109,30 @@ def kernel_contracts() -> dict[str, KernelContract]:
 
 
 # ---------------------------------------------------------- VMEM tile models
+#: widest packed operand row the budget is priced at. Kernels take packed
+#: words a whole row block at a time (``kernels.words``), so their
+#: residency grows with the row width, not with ``block_k``; the widest
+#: packed contraction of the served models is d_ff 6144.
+PACKED_ROW_BITS = 8192
+
+
+def packed_rows_vmem(block_m: int) -> int:
+    """One packed operand's row block: the double-buffered ``(block_m,
+    W)`` words plus their transposed ``(W, block_m)`` scratch."""
+    return 3 * block_m * (PACKED_ROW_BITS // LANE_BITS) * 4
+
+
 def matmul_vmem(block_m: int, block_n: int, block_k: int,
                 packed: bool) -> int:
-    """Spike-matmul sweep residency: one x tile (packed words + the int8
-    unpack scratch, or the int8 tile directly), one f32 w tile, one f32
-    accumulator tile, plus the scalar-prefetched metadata row.  The family
-    budget is the max over its forward and BACKWARD sweeps — the dx
-    backward holds all-f32 tiles (cotangent + w + dx accumulator) plus the
-    cached-current tile its fused surrogate factor re-reads."""
+    """Spike-matmul sweep residency: the x operand (packed: the row
+    block's words + their transposed scratch + the f32 bits of one K-tile;
+    dense: the int8 tile), one f32 w tile, one f32 accumulator tile, plus
+    the scalar-prefetched metadata row.  The family budget is the max over
+    its forward and BACKWARD sweeps — the dx backward holds all-f32 tiles
+    (cotangent + w + dx accumulator) plus the cached-current tile its
+    fused surrogate factor re-reads."""
     if packed:
-        x = block_m * (block_k // LANE_BITS) * 4 + block_m * block_k
+        x = packed_rows_vmem(block_m) + block_m * block_k * 4
     else:
         x = block_m * block_k
     meta = 4 * (block_k // 8 + 2)            # vld row + nact/kmap scalars
@@ -135,9 +149,11 @@ def fused_pe_vmem(block_m: int, block_n: int, block_k: int,
                   packed: bool) -> int:
     """Fused PE adds to the matmul sweep: bias row, residual tile, LIF
     state tiles (v f32 + s int8), the Q tile for the write-back mask, the
-    emitted spike tile (packed: words + vld row), and the f32 membrane-
-    current tile the training forward writes back (``emit_current`` — the
-    residual cache the event-skipped backward differentiates from)."""
+    emitted spike tile (packed: the output row block and a packed
+    residual row block, each with its transposed scratch), and the f32
+    membrane-current tile the training forward writes back
+    (``emit_current`` — the residual cache the event-skipped backward
+    differentiates from)."""
     extra = (block_n * 4                      # bias
              + block_m * block_n * 4          # residual
              + block_m * block_n * 5          # v_prev f32 + s_prev int8
@@ -145,12 +161,11 @@ def fused_pe_vmem(block_m: int, block_n: int, block_k: int,
              + block_m * block_n              # emitted int8 spike tile
              + block_m * block_n * 4)         # emit_current f32 tile
     if packed:
-        extra += block_m * (block_n // LANE_BITS) * 4 + 4 * (block_n // 8)
+        extra += 2 * packed_rows_vmem(block_m)
     return matmul_vmem(block_m, block_n, block_k, packed) + extra
 
 
 def pack_vmem(block_m: int, block_n: int, block_k: int, packed: bool) -> int:
-    """Pack/unpack trio: one int8 tile in, words + vld/occ rows out."""
-    return (block_m * block_k
-            + block_m * (block_k // LANE_BITS) * 4
-            + 2 * 4 * (block_k // 8))
+    """Pack/unpack pair: one int8 tile in, the row block's words out (the
+    vld/occ maps live in SMEM)."""
+    return block_m * block_k + packed_rows_vmem(block_m)

@@ -66,9 +66,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.events import (LANE_BITS, compact_kmap, head_lane_masks,
-                            pack_words, unpack_words)
+from ...core.events import LANE_BITS, compact_kmap, head_lane_masks
 from ..gating import accum_tile
+from ..words import (pack_tile_t, row_spec, tile_bits_t, transpose_words,
+                     x_operand_spec)
 
 Array = jax.Array
 
@@ -78,9 +79,12 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
                  with_state: bool, apply_qk: bool, emit_vld: bool,
                  emit_current: bool,
                  m_valid: int, n_valid: int, block_m: int, block_n: int,
-                 packed_in: bool, packed_q: bool, packed_residual: bool,
-                 packed_out: bool, skip: str = "dense",
+                 block_k: int, packed_in: bool, packed_q: bool,
+                 packed_residual: bool, packed_out: bool, skip: str = "dense",
                  heads: tuple[int, int] | None = None):
+    wpb_k = block_k // LANE_BITS
+    wpb_n = block_n // LANE_BITS
+
     def kernel(*allrefs):
         # scalar-prefetch block: vld map (dense) or the compacted routing
         # tables (gated / two_level) from core.events.compact_kmap
@@ -99,19 +103,28 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
         v_ref = next(it) if with_state else None
         s_ref = next(it) if with_state else None
         q_ref = next(it) if apply_qk else None
+        sel_ref = next(it) if apply_qk and packed_q and heads else None
         spike_ref = next(it)
         vout_ref = next(it) if with_state else None
         cnt_ref = next(it) if emit_vld else None
         cur_ref = next(it) if emit_current else None
         acc_ref = next(it)
+        # transposed packed-word scratches (kernels.words)
+        xt_ref = next(it) if packed_in else None
+        rt_ref = next(it) if packed_residual else None
+        ot_ref = next(it) if packed_out else None
 
         i = pl.program_id(0)
         j = pl.program_id(1)
         k = pl.program_id(2)
+        kb = k if skip == "dense" else kmap_ref[i, k]
+        src = xt_ref if packed_in else x_ref
 
         @pl.when(k == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
+            if packed_in:
+                transpose_words(x_ref, src)
 
         if skip == "dense":
             # event skip: silent block -> no MXU (bytes still stream)
@@ -123,10 +136,9 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
 
         @pl.when(gate)
         def _accum():
-            occ_bits = (occ_ref[i, kmap_ref[i, k]]
-                        if skip == "two_level" else None)
-            accum_tile(acc_ref, x_ref, w_ref, packed_in=packed_in,
-                       occ_bits=occ_bits)
+            accum_tile(acc_ref, src, w_ref, wpb=wpb_k if packed_in else None,
+                       kb=kb, occ_bits=(occ_ref[i, kb] if skip == "two_level"
+                                        else None))
 
         @pl.when(k == pl.num_programs(2) - 1)
         def _writeback():
@@ -135,7 +147,8 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
                 cur = cur + b_ref[...].astype(jnp.float32)
             if with_residual:
                 if packed_residual:  # binary spike shortcut, stored packed
-                    cur = cur + unpack_words(r_ref[...], jnp.float32)
+                    transpose_words(r_ref, rt_ref)
+                    cur = cur + tile_bits_t(rt_ref, j, wpb_n).T
                 else:
                     cur = cur + r_ref[...].astype(jnp.float32)
             if emit_current:
@@ -171,10 +184,6 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
                 # columns. Static per-head slices / lane masks keep this on
                 # the VPU (no gathers); pad columns map to no head.
                 hq, dh = heads
-                if packed_q:
-                    words = q_ref[...]
-                    sels = head_lane_masks(hq, dh,
-                                           words.shape[1] * LANE_BITS)
                 cols = (jax.lax.broadcasted_iota(
                     jnp.int32, (block_m, block_n), 1) + j * block_n)
                 head_of_col = cols // dh
@@ -182,7 +191,7 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
                 for hh in range(hq):
                     if packed_q:     # per-head popcount over the word lanes
                         rs = jnp.sum(jax.lax.population_count(
-                            words & sels[hh][None, :]), axis=1,
+                            q_ref[...] & sel_ref[hh:hh + 1, :]), axis=1,
                             keepdims=True).astype(jnp.float32)
                     else:
                         rs = q_ref[:, hh * dh:(hh + 1) * dh].astype(
@@ -199,11 +208,15 @@ def _make_kernel(*, tau: float, v_th: float, soft_reset: bool,
                 spk = spk * ((rows < m_valid) & (cols < n_valid)
                              ).astype(jnp.float32)
             if packed_out:           # compress in-register before the write
-                spike_ref[...] = pack_words(spk)
+                ot_ref[pl.ds(j * wpb_n, wpb_n), :] = pack_tile_t(spk)
+
+                @pl.when(j == pl.num_programs(1) - 1)  # row block complete
+                def _store():
+                    spike_ref[...] = ot_ref[...].T
             else:
                 spike_ref[...] = spk.astype(spike_ref.dtype)
             if emit_vld:             # on-the-fly next-layer PipeSDA metadata
-                cnt_ref[0, 0] = jnp.sum(spk).astype(jnp.int32)
+                cnt_ref[i, j] = jnp.sum(spk).astype(jnp.int32)
 
     return kernel
 
@@ -284,8 +297,9 @@ def fused_pe_pallas(x: Array, w: Array, vld_cnt: Array,
         with_state=with_state, apply_qk=q is not None, emit_vld=emit_vld,
         emit_current=emit_current,
         m_valid=m_valid or m, n_valid=n_valid or n,
-        block_m=block_m, block_n=block_n, packed_in=packed_in,
-        packed_q=packed_q, packed_residual=packed_residual,
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        packed_in=packed_in, packed_q=packed_q,
+        packed_residual=packed_residual,
         packed_out=packed_out, skip=skip, heads=heads)
 
     # scalar-prefetch set: vld map (dense) or the compacted routing tables
@@ -312,19 +326,21 @@ def fused_pe_pallas(x: Array, w: Array, vld_cnt: Array,
         def w_idx(i, j, s, nact_ref, kmap_ref, *rest):
             return (kmap_ref[i, s], j)
 
-    x_bk = block_k // LANE_BITS if packed_in else block_k
-    in_specs = [
-        pl.BlockSpec((block_m, x_bk), x_idx),
-        pl.BlockSpec((block_k, block_n), w_idx),
-    ]
+    x_spec, scratch, _ = x_operand_spec(x, packed_in, block_m, block_k,
+                                        x_idx)
+    in_specs = [x_spec, pl.BlockSpec((block_k, block_n), w_idx)]
     operands = [x, w]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, block_n),
                                      lambda i, j, kk, *refs: (0, j)))
         operands.append(bias.reshape(1, n))
-    if residual is not None:
-        r_bn = block_n // LANE_BITS if packed_residual else block_n
-        in_specs.append(pl.BlockSpec((block_m, r_bn),
+    if packed_residual:
+        in_specs.append(row_spec(block_m, n // LANE_BITS,
+                                 lambda i, j, kk, *refs: i))
+        operands.append(residual)
+        scratch.append(pltpu.VMEM((n // LANE_BITS, block_m), jnp.int32))
+    elif residual is not None:
+        in_specs.append(pl.BlockSpec((block_m, block_n),
                                      lambda i, j, kk, *refs: (i, j)))
         operands.append(residual)
     if with_state:
@@ -336,11 +352,19 @@ def fused_pe_pallas(x: Array, w: Array, vld_cnt: Array,
         in_specs.append(pl.BlockSpec((block_m, dq),
                                      lambda i, j, kk, *refs: (i, 0)))
         operands.append(q)
+        if packed_q and heads is not None:
+            # per-head word masks: a constant operand, since a kernel body
+            # cannot capture one
+            sel = head_lane_masks(*heads, dq * LANE_BITS)
+            in_specs.append(pl.BlockSpec(sel.shape,
+                                         lambda i, j, kk, *refs: (0, 0)))
+            operands.append(sel)
 
     if packed_out:
         out_shape = [jax.ShapeDtypeStruct((m, n // LANE_BITS), jnp.int32)]
-        out_specs = [pl.BlockSpec((block_m, block_n // LANE_BITS),
-                                  lambda i, j, kk, *refs: (i, j))]
+        out_specs = [row_spec(block_m, n // LANE_BITS,
+                              lambda i, j, kk, *refs: i)]
+        scratch.append(pltpu.VMEM((n // LANE_BITS, block_m), jnp.int32))
     else:
         out_shape = [jax.ShapeDtypeStruct((m, n), jnp.int8)]
         out_specs = [pl.BlockSpec((block_m, block_n),
@@ -352,8 +376,7 @@ def fused_pe_pallas(x: Array, w: Array, vld_cnt: Array,
     if emit_vld:
         out_shape.append(jax.ShapeDtypeStruct(
             (m // block_m, n // block_n), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda i, j, kk, *refs: (i, j)))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if emit_current:
         out_shape.append(jax.ShapeDtypeStruct((m, n), jnp.float32))
         out_specs.append(pl.BlockSpec((block_m, block_n),
@@ -366,7 +389,8 @@ def fused_pe_pallas(x: Array, w: Array, vld_cnt: Array,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)]
+            + scratch,
         ),
         out_shape=out_shape,
         interpret=interpret,

@@ -12,88 +12,91 @@ The stripe loop is a PYTHON loop over the tile's word-columns (block_k/32
 iterations, unrolled at trace time) with a ``pl.when`` per stripe, so the
 skip is a predicated branch — cheap on silent stripes, and the sub-dots
 stay MXU-shaped at (block_m, 32) @ (32, block_n).
+
+A packed x arrives as the row block's TRANSPOSED words (``kernels.words``):
+``x_ref`` is then the ``(K/32, block_m)`` scratch and ``kb`` the k-block
+to read from it.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..core.events import LANE_BITS, unpack_words
+from ..core.events import LANE_BITS
+from .words import dot_t, stripe_bits_t, tile_bits_t
 
 
-def accum_tile(o_ref, x_ref, w_ref, *, packed_in: bool,
+def _occupied(occ_bits, c: int):
+    # arithmetic >> keeps bit 31 extractable (the &1 masks the sign fill)
+    return jnp.bitwise_and(jnp.right_shift(occ_bits, c), 1) != 0
+
+
+def accum_tile(o_ref, x_ref, w_ref, *, wpb: int | None = None, kb=None,
                occ_bits=None) -> None:
     """o_ref += x_tile @ w_tile.
 
-    ``x_ref``: (block_m, block_k) dense spikes or (block_m, block_k/32)
-    int32 words when ``packed_in``. ``w_ref``: (block_k, block_n).
-    ``occ_bits``: optional int32 scalar — the word-occupancy bitmap for THIS
-    tile; when given, only occupied 32-column stripes touch the MXU.
+    ``x_ref``: (block_m, block_k) dense spikes, or — with ``wpb`` (words
+    per k-block) set — the transposed packed words of the row block, read
+    at k-block ``kb``. ``w_ref``: (block_k, block_n). ``occ_bits``:
+    optional int32 scalar — the word-occupancy bitmap for THIS tile; when
+    given, only occupied 32-column stripes touch the MXU.
     """
     if occ_bits is None:
-        if packed_in:                  # decompress the K-tile in VMEM
-            x = unpack_words(x_ref[...], jnp.float32)
+        if wpb is not None:            # decompress the K-tile in VMEM
+            o_ref[...] += dot_t(tile_bits_t(x_ref, kb, wpb), w_ref[...])
         else:
             x = x_ref[...].astype(jnp.float32)
-        w = w_ref[...].astype(jnp.float32)
-        o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+            o_ref[...] += jnp.dot(x, w_ref[...].astype(jnp.float32),
+                                  preferred_element_type=jnp.float32)
         return
 
-    if packed_in:
-        wpb = x_ref.shape[-1]
-    else:
-        assert x_ref.shape[-1] % LANE_BITS == 0, x_ref.shape
-        wpb = x_ref.shape[-1] // LANE_BITS
-    assert wpb <= LANE_BITS, (wpb, "occ bitmap covers <= 32 word-columns")
-
-    for c in range(wpb):
-        # arithmetic >> keeps bit 31 extractable (the &1 masks the sign fill)
-        @pl.when(jnp.bitwise_and(jnp.right_shift(occ_bits, c), 1) != 0)
+    n_stripes = wpb if wpb is not None else x_ref.shape[-1] // LANE_BITS
+    assert n_stripes <= LANE_BITS, (n_stripes,
+                                    "occ bitmap covers <= 32 word-columns")
+    for c in range(n_stripes):
+        @pl.when(_occupied(occ_bits, c))
         def _stripe(c=c):
-            if packed_in:
-                xs = unpack_words(x_ref[:, c:c + 1], jnp.float32)
+            ws = w_ref[c * LANE_BITS:(c + 1) * LANE_BITS, :]
+            if wpb is not None:
+                o_ref[...] += dot_t(stripe_bits_t(x_ref, kb, wpb, c), ws)
             else:
                 xs = x_ref[:, c * LANE_BITS:(c + 1) * LANE_BITS]
-                xs = xs.astype(jnp.float32)
-            ws = w_ref[c * LANE_BITS:(c + 1) * LANE_BITS, :]
-            o_ref[...] += jnp.dot(xs, ws.astype(jnp.float32),
-                                  preferred_element_type=jnp.float32)
+                o_ref[...] += jnp.dot(xs.astype(jnp.float32),
+                                      ws.astype(jnp.float32),
+                                      preferred_element_type=jnp.float32)
 
 
-def accum_tile_t(o_ref, x_ref, g_ref, *, packed_in: bool,
+def accum_tile_t(o_ref, x_ref, g_ref, *, wpb: int | None = None, kb=None,
                  occ_bits=None) -> None:
     """o_ref += x_tileᵀ @ g_tile — the weight-gradient contraction.
 
-    ``x_ref``: (block_m, block_k) dense spikes or (block_m, block_k/32)
-    int32 words when ``packed_in``. ``g_ref``: (block_m, block_n) f32
-    cotangent. ``o_ref``: (block_k, block_n). ``occ_bits``: optional
-    word-occupancy bitmap for THIS x-tile; a silent 32-column k-stripe of
-    x contributes nothing to output ROWS [c*32, (c+1)*32), so the stripe's
-    (32, block_m) @ (block_m, block_n) sub-dot is elided entirely.
+    ``x_ref``: (block_m, block_k) dense spikes, or the transposed packed
+    words of the row block read at k-block ``kb`` (``wpb`` set).
+    ``g_ref``: (block_m, block_n) f32 cotangent. ``o_ref``: (block_k,
+    block_n). ``occ_bits``: optional word-occupancy bitmap for THIS
+    x-tile; a silent 32-column k-stripe of x contributes nothing to output
+    ROWS [c*32, (c+1)*32), so the stripe's (32, block_m) @ (block_m,
+    block_n) sub-dot is elided entirely.
     """
     g = g_ref[...].astype(jnp.float32)
     if occ_bits is None:
-        if packed_in:
-            x = unpack_words(x_ref[...], jnp.float32)
+        if wpb is not None:
+            xt = tile_bits_t(x_ref, kb, wpb)
         else:
-            x = x_ref[...].astype(jnp.float32)
-        o_ref[...] += jnp.dot(x.T, g, preferred_element_type=jnp.float32)
+            xt = x_ref[...].astype(jnp.float32).T
+        o_ref[...] += jnp.dot(xt, g, preferred_element_type=jnp.float32)
         return
 
-    if packed_in:
-        wpb = x_ref.shape[-1]
-    else:
-        assert x_ref.shape[-1] % LANE_BITS == 0, x_ref.shape
-        wpb = x_ref.shape[-1] // LANE_BITS
-    assert wpb <= LANE_BITS, (wpb, "occ bitmap covers <= 32 word-columns")
-
-    for c in range(wpb):
-        @pl.when(jnp.bitwise_and(jnp.right_shift(occ_bits, c), 1) != 0)
+    n_stripes = wpb if wpb is not None else x_ref.shape[-1] // LANE_BITS
+    assert n_stripes <= LANE_BITS, (n_stripes,
+                                    "occ bitmap covers <= 32 word-columns")
+    for c in range(n_stripes):
+        @pl.when(_occupied(occ_bits, c))
         def _stripe(c=c):
-            if packed_in:
-                xs = unpack_words(x_ref[:, c:c + 1], jnp.float32)
+            if wpb is not None:
+                xst = stripe_bits_t(x_ref, kb, wpb, c)
             else:
-                xs = x_ref[:, c * LANE_BITS:(c + 1) * LANE_BITS]
-                xs = xs.astype(jnp.float32)
+                xst = x_ref[:, c * LANE_BITS:(c + 1) * LANE_BITS].astype(
+                    jnp.float32).T
             o_ref[c * LANE_BITS:(c + 1) * LANE_BITS, :] += jnp.dot(
-                xs.T, g, preferred_element_type=jnp.float32)
+                xst, g, preferred_element_type=jnp.float32)

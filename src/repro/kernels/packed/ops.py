@@ -30,14 +30,16 @@ def _on_tpu() -> bool:
 
 
 def _over_leading(fn, x: Array):
-    """Run a 2-D-core pallas wrapper over arbitrary leading dims via vmap."""
+    """Run a 2-D-core pallas wrapper over arbitrary leading dims. Both
+    kernels work row block by row block and each leading slice is already
+    padded to whole row blocks, so the slices stack along M: one call, and
+    every output splits back along its first axis."""
     if x.ndim == 2:
         return fn(x)
     lead = x.shape[:-2]
-    flat = x.reshape(-1, *x.shape[-2:])
-    out = jax.vmap(fn)(flat)
+    out = fn(x.reshape(-1, x.shape[-1]))
     return jax.tree_util.tree_map(
-        lambda a: a.reshape(*lead, *a.shape[1:]), out)
+        lambda a: a.reshape(*lead, -1, a.shape[-1]), out)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_k",

@@ -17,8 +17,9 @@ via the vld count map, ``gated`` walks a COMPACTED active-block list along
 the transposed axis (``compact_kmap(vldᵀ)``) so silent tiles are never
 DMA'd, and ``two_level`` additionally elides silent 32-column k-stripes
 via the word-occupancy bitmap (a silent stripe of x contributes nothing to
-output rows [c*32, (c+1)*32)). Packed spike words stream as-is: the K-tile
-is unpacked in VMEM right before the transpose MXU issue — no dense
+output rows [c*32, (c+1)*32)). Packed spike words stream as-is, a row
+block at a time (``kernels.words``): the K-tile is unpacked in VMEM
+already transposed, right before the MXU issue — no dense
 unpack-then-matmul round trip through HBM.
 """
 from __future__ import annotations
@@ -33,6 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.surrogate import surrogate_grad
 from ...core.events import LANE_BITS
 from ..gating import accum_tile_t
+from ..words import transpose_words, x_operand_spec
 
 Array = jax.Array
 
@@ -116,8 +118,8 @@ def spike_matmul_dx_pallas(g: Array, w: Array, v: Array | None = None, *,
     return out[0], g
 
 
-def _make_dw_kernel(packed_in: bool):
-    def kernel(vld_ref, x_ref, g_ref, o_ref):
+def _make_dw_kernel(wpb: int | None):
+    def kernel(vld_ref, x_ref, g_ref, o_ref, *scratch):
         kb = pl.program_id(0)
         mb = pl.program_id(2)
 
@@ -127,7 +129,11 @@ def _make_dw_kernel(packed_in: bool):
 
         @pl.when(vld_ref[mb, kb] > 0)    # event skip: silent block -> no MXU
         def _accum():
-            accum_tile_t(o_ref, x_ref, g_ref, packed_in=packed_in)
+            src = x_ref
+            if wpb is not None:          # a new row block every step
+                src = scratch[0]
+                transpose_words(x_ref, src)
+            accum_tile_t(o_ref, src, g_ref, wpb=wpb, kb=kb)
 
     return kernel
 
@@ -150,17 +156,11 @@ def spike_matmul_dw_pallas(x: Array, g: Array, vld_cnt: Array, *,
     n = g.shape[1]
     assert g.shape[0] == m and m % block_m == 0 and k % block_k == 0 \
         and n % block_n == 0, (x.shape, g.shape, block_m, block_n, block_k)
-    if packed_in:
-        assert x.dtype == jnp.int32 and block_k % LANE_BITS == 0
-        x_spec = pl.BlockSpec((block_m, block_k // LANE_BITS),
-                              lambda kk, j, i, vld: (i, kk))
-    else:
-        x_spec = pl.BlockSpec((block_m, block_k),
-                              lambda kk, j, i, vld: (i, kk))
-
+    x_spec, scratch, wpb = x_operand_spec(x, packed_in, block_m, block_k,
+                                          lambda kk, j, i, vld: (i, kk))
     grid = (k // block_k, n // block_n, m // block_m)
     return pl.pallas_call(
-        _make_dw_kernel(packed_in),
+        _make_dw_kernel(wpb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -171,18 +171,19 @@ def spike_matmul_dw_pallas(x: Array, g: Array, vld_cnt: Array, *,
             ],
             out_specs=pl.BlockSpec((block_k, block_n),
                                    lambda kk, j, i, vld: (kk, j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
         interpret=interpret,
     )(vld_cnt, x, g)
 
 
-def _make_dw_gated_kernel(packed_in: bool, two_level: bool):
+def _make_dw_gated_kernel(wpb: int | None, two_level: bool):
     def kernel(*refs):
         if two_level:
-            nact_ref, mmap_ref, occ_ref, x_ref, g_ref, o_ref = refs
+            nact_ref, mmap_ref, occ_ref, x_ref, g_ref, o_ref, *scratch = refs
         else:
-            nact_ref, mmap_ref, x_ref, g_ref, o_ref = refs
+            nact_ref, mmap_ref, x_ref, g_ref, o_ref, *scratch = refs
         kb = pl.program_id(0)
         s = pl.program_id(2)
 
@@ -194,9 +195,13 @@ def _make_dw_gated_kernel(packed_in: bool, two_level: bool):
         # the BlockSpec never changes -> no DMA; the predicate skips the MXU
         @pl.when(s < nact_ref[kb])
         def _accum():
-            occ_bits = occ_ref[mmap_ref[kb, s], kb] if two_level else None
-            accum_tile_t(o_ref, x_ref, g_ref, packed_in=packed_in,
-                         occ_bits=occ_bits)
+            src = x_ref
+            if wpb is not None:
+                src = scratch[0]
+                transpose_words(x_ref, src)
+            accum_tile_t(o_ref, src, g_ref, wpb=wpb, kb=kb,
+                         occ_bits=(occ_ref[mmap_ref[kb, s], kb] if two_level
+                                   else None))
 
     return kernel
 
@@ -239,15 +244,11 @@ def spike_matmul_dw_gated_pallas(x: Array, g: Array, nact_t: Array,
     def g_idx(kk, j, s, nact_ref, mmap_ref, *rest):
         return (mmap_ref[kk, s], j)
 
-    if packed_in:
-        assert x.dtype == jnp.int32 and block_k % LANE_BITS == 0
-        x_spec = pl.BlockSpec((block_m, block_k // LANE_BITS), x_idx)
-    else:
-        x_spec = pl.BlockSpec((block_m, block_k), x_idx)
-
+    x_spec, scratch, wpb = x_operand_spec(x, packed_in, block_m, block_k,
+                                          x_idx)
     grid = (k // block_k, n // block_n, m // block_m)
     return pl.pallas_call(
-        _make_dw_gated_kernel(packed_in, two_level),
+        _make_dw_gated_kernel(wpb, two_level),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=npf,
             grid=grid,
@@ -257,6 +258,7 @@ def spike_matmul_dw_gated_pallas(x: Array, g: Array, nact_t: Array,
             ],
             out_specs=pl.BlockSpec((block_k, block_n),
                                    lambda kk, j, s, *refs: (kk, j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((k, n), jnp.float32),
         interpret=interpret,

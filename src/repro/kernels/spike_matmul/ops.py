@@ -37,7 +37,10 @@ def check_block_contract(ps: PackedSpikes, block_m: int, block_k: int,
                          what: str = "packed operand") -> None:
     """The packed-operand block-shape contract: a PackedSpikes pins its tile
     grid at pack time; the consuming kernel must tile identically or its
-    vld_cnt map is routing garbage."""
+    vld_cnt map is routing garbage. The words themselves enter kernels a
+    whole ``block_m``-row block at a time (``kernels.words``), so
+    ``block_m`` also fixes their row padding; ``block_k`` matters only to
+    the vld_cnt/occ maps."""
     if (ps.block_m, ps.block_k) != (block_m, block_k):
         raise ValueError(
             f"{what} was packed on (block_m={ps.block_m}, "

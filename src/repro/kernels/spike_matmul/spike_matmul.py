@@ -10,8 +10,9 @@ VMEM as operands become resident).
 
   x  : [M, K] int8  spikes (0/1)           — activations
        or, with ``packed_in``, [M, K/32] int32 bit-packed words (the
-       event-compressed HBM format, ``core.events.PackedSpikes``): the
-       K-tile is unpacked in VMEM right before the MXU, so the 8x-smaller
+       event-compressed HBM format, ``core.events.PackedSpikes``),
+       streamed a row block at a time (``kernels.words``): the K-tile is
+       unpacked in VMEM right before the MXU, so the 8x-smaller
        representation is what crosses HBM
   w  : [K, N] bf16/f32 weights
   out: [M, N] f32 = x @ w, accumulated over the K grid axis
@@ -28,31 +29,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.events import LANE_BITS, unpack_words
+from ...core.events import LANE_BITS
 from ..gating import accum_tile
+from ..words import transpose_words, x_operand_spec
 
 Array = jax.Array
 
 
-def _make_kernel(packed_in: bool):
-    def kernel(vld_ref, x_ref, w_ref, o_ref):
+def _make_kernel(wpb: int | None):
+    def kernel(vld_ref, x_ref, w_ref, o_ref, *scratch):
         i = pl.program_id(0)
         k = pl.program_id(2)
+        src = scratch[0] if wpb is not None else x_ref
 
         @pl.when(k == 0)
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
+            if wpb is not None:          # the row block's words, transposed
+                transpose_words(x_ref, src)
 
-        cnt = vld_ref[i, k]
-
-        @pl.when(cnt > 0)                # event skip: silent block -> no MXU
+        @pl.when(vld_ref[i, k] > 0)      # event skip: silent block -> no MXU
         def _accum():
-            if packed_in:                # decompress the K-tile in VMEM
-                x = unpack_words(x_ref[...], jnp.float32)
-            else:
-                x = x_ref[...].astype(jnp.float32)
-            w = w_ref[...].astype(jnp.float32)
-            o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+            accum_tile(o_ref, src, w_ref, wpb=wpb, kb=k)
 
     return kernel
 
@@ -71,17 +69,11 @@ def spike_matmul_pallas(x: Array, w: Array, vld_cnt: Array, *,
     k = x.shape[1] * LANE_BITS if packed_in else x.shape[1]
     assert k == k2 and m % block_m == 0 and k % block_k == 0 \
         and n % block_n == 0, (x.shape, w.shape, block_m, block_n, block_k)
-    if packed_in:
-        assert x.dtype == jnp.int32 and block_k % LANE_BITS == 0
-        x_spec = pl.BlockSpec((block_m, block_k // LANE_BITS),
-                              lambda i, j, kk, vld: (i, kk))
-    else:
-        x_spec = pl.BlockSpec((block_m, block_k),
-                              lambda i, j, kk, vld: (i, kk))
-
+    x_spec, scratch, wpb = x_operand_spec(x, packed_in, block_m, block_k,
+                                          lambda i, j, kk, vld: (i, kk))
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
-        _make_kernel(packed_in),
+        _make_kernel(wpb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -92,32 +84,36 @@ def spike_matmul_pallas(x: Array, w: Array, vld_cnt: Array, *,
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
                                    lambda i, j, kk, vld: (i, j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
     )(vld_cnt, x, w)
 
 
-def _make_gated_kernel(packed_in: bool, two_level: bool):
+def _make_gated_kernel(wpb: int | None, two_level: bool):
     def kernel(*refs):
         if two_level:
-            nact_ref, kmap_ref, occ_ref, x_ref, w_ref, o_ref = refs
+            nact_ref, kmap_ref, occ_ref, x_ref, w_ref, o_ref, *scratch = refs
         else:
-            nact_ref, kmap_ref, x_ref, w_ref, o_ref = refs
+            nact_ref, kmap_ref, x_ref, w_ref, o_ref, *scratch = refs
         i = pl.program_id(0)
         s = pl.program_id(2)
+        kb = kmap_ref[i, s]
+        src = scratch[0] if wpb is not None else x_ref
 
         @pl.when(s == 0)
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
+            if wpb is not None:
+                transpose_words(x_ref, src)
 
         # steps past nact[i] revisit the last active block index, so the
         # BlockSpec never changes -> no DMA; this predicate skips the MXU
         @pl.when(s < nact_ref[i])
         def _accum():
-            occ_bits = occ_ref[i, kmap_ref[i, s]] if two_level else None
-            accum_tile(o_ref, x_ref, w_ref, packed_in=packed_in,
-                       occ_bits=occ_bits)
+            accum_tile(o_ref, src, w_ref, wpb=wpb, kb=kb,
+                       occ_bits=occ_ref[i, kb] if two_level else None)
 
     return kernel
 
@@ -161,15 +157,11 @@ def spike_matmul_gated_pallas(x: Array, w: Array, nact: Array, kmap: Array,
     def w_idx(i, j, s, nact_ref, kmap_ref, *rest):
         return (kmap_ref[i, s], j)
 
-    if packed_in:
-        assert x.dtype == jnp.int32 and block_k % LANE_BITS == 0
-        x_spec = pl.BlockSpec((block_m, block_k // LANE_BITS), x_idx)
-    else:
-        x_spec = pl.BlockSpec((block_m, block_k), x_idx)
-
+    x_spec, scratch, wpb = x_operand_spec(x, packed_in, block_m, block_k,
+                                          x_idx)
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
-        _make_gated_kernel(packed_in, two_level),
+        _make_gated_kernel(wpb, two_level),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=npf,
             grid=grid,
@@ -179,6 +171,7 @@ def spike_matmul_gated_pallas(x: Array, w: Array, nact: Array, kmap: Array,
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
                                    lambda i, j, s, *refs: (i, j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,

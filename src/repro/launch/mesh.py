@@ -13,25 +13,20 @@ the 2-pod extension DCN-feasible.
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def _auto(axes):
-    # jax.sharding.AxisType only exists on newer jax; older releases have
-    # implicitly-Auto axes and make_mesh has no axis_types kwarg
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else (axis_type.Auto,) * len(axes)
-
-
-def _make_mesh(shape, axes, devices) -> Mesh:
-    kinds = _auto(axes)
-    if kinds is None:
-        return jax.make_mesh(shape, axes, devices=devices)
-    return jax.make_mesh(shape, axes, devices=devices, axis_types=kinds)
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """The repo's one mesh constructor. Axes are Auto: the sharding code
+    (``models.sharding.shard_act``, the vocab-sharded embedding gather)
+    places activations with ``with_sharding_constraint``, which
+    ``jax.make_mesh``'s default Explicit axes reject."""
+    if devices is None:
+        devices = jax.devices()[:math.prod(shape)]
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -44,14 +39,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"mesh {shape} needs {need} devices, found {len(devices)} — "
             "run under launch/dryrun.py (which forces 512 host devices) or "
             "on real hardware")
-    return _make_mesh(shape, axes, devices[:need])
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
-    """Small mesh over host devices for unit tests (requires the test to
-    set --xla_force_host_platform_device_count)."""
-    need = math.prod(shape)
-    return _make_mesh(shape, axes, jax.devices()[:need])
+    return make_mesh(shape, axes, devices[:need])
 
 
 def make_elastic_mesh(n_pods_alive: int, *, pod_shape=(16, 16)) -> Mesh:
@@ -63,4 +51,4 @@ def make_elastic_mesh(n_pods_alive: int, *, pod_shape=(16, 16)) -> Mesh:
     devices = jax.devices()
     if len(devices) < need:
         raise RuntimeError(f"need {need} devices for elastic mesh {shape}")
-    return _make_mesh(shape, axes, devices[:need])
+    return make_mesh(shape, axes, devices[:need])

@@ -50,6 +50,8 @@ def main() -> None:
     ap.add_argument("--chaos-seed", type=int, default=0)
     args = ap.parse_args()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     from ..configs import get_config, reduced as reduce_cfg, build_model
     from ..serve import (Engine, EngineConfig, ReplicaRouter,
                          demo_chaos_plan)

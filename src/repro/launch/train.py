@@ -53,14 +53,16 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
     from ..configs import get_config, reduced as reduce_cfg, build_model
     from ..data import ShardedLoader, SyntheticTokenDataset
+    from .mesh import make_mesh
     from ..models import sharding as shd
     from ..optim import linear_warmup_cosine
     from ..train import (ElasticRunner, make_train_step, train_state_init,
                          TrainState)
     from ..train.elastic import ElasticConfig
-    from jax.sharding import Mesh
 
     overrides = {}
     if args.spiking:
@@ -83,11 +85,11 @@ def main() -> None:
     n_dev = len(jax.devices())
 
     def mesh_full():
-        return jax.make_mesh((n_dev,), ("data",))
+        return make_mesh((n_dev,), ("data",))
 
     def mesh_half():
-        return jax.make_mesh((max(n_dev // 2, 1),), ("data",),
-                             devices=jax.devices()[:max(n_dev // 2, 1)])
+        return make_mesh((max(n_dev // 2, 1),), ("data",),
+                         devices=jax.devices()[:max(n_dev // 2, 1)])
 
     ds = SyntheticTokenDataset(cfg.vocab_size, args.seq + 1)
 

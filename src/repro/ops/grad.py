@@ -628,14 +628,15 @@ def _dense_lif_grad(kernels: str, cfg: LIFConfig, qk_threshold: float,
                                  (m, kv_heads, g, dh)).reshape(m, h * dh)
         return s
 
+    def _current(ops):
+        cur = ops["x"] @ ops["w"]
+        return cur + ops["b"] if has_bias else cur
+
     def ref_fwd(ops):
         # grouped KV (kv_heads < h): the matmul stays on the UNEXPANDED
         # weight — the group expansion happens inside the mask broadcast,
         # so its backward sums group cotangents into the shared columns
-        cur = ops["x"] @ ops["w"]
-        if has_bias:
-            cur = cur + ops["b"]
-        return _tail(cur, ops.get("q"))
+        return _tail(_current(ops), ops.get("q"))
 
     if kernels == "reference":
         return ref_fwd
@@ -644,9 +645,7 @@ def _dense_lif_grad(kernels: str, cfg: LIFConfig, qk_threshold: float,
         if not _pallas_training():
             # identical math as jnp; the cached current stays in the
             # GROUPED (unexpanded-weight) layout the vjp differentiates
-            cur = ops["x"] @ ops["w"]
-            if has_bias:
-                cur = cur + ops["b"]
+            cur = _current(ops)
             return _tail(cur, ops.get("q")), (cur if with_current else None)
         from .impls import _dense_lif_fused
         from .spike_tensor import SpikeTensor
@@ -689,10 +688,11 @@ def _dense_lif_grad(kernels: str, cfg: LIFConfig, qk_threshold: float,
 
         _, vjp = jax.vjp(lambda d: _tail(d["cur"], d.get("q")), diff)
         (dd,) = vjp(g)
-        dcur = dd["cur"]
-        grads = {"x": dcur @ ops["w"].T, "w": ops["x"].T @ dcur}
-        if has_bias:
-            grads["b"] = dcur.sum(axis=0).reshape(ops["b"].shape)
+        # the matmul's own vjp: the same transposed contractions, in the
+        # same order, that autodiff of ``ref_fwd`` runs
+        _, mm_vjp = jax.vjp(_current, {k: ops[k] for k in ("x", "w", "b")
+                                       if k in ops})
+        (grads,) = mm_vjp(dd["cur"])
         if "q" in dd:
             grads["q"] = dd["q"]
         return ({k: grads.get(k) for k in ops},)

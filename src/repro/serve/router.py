@@ -55,6 +55,9 @@ class ReplicaRouter:
                    rng_seed=rng_seed + i,
                    faults=faults.view(i) if faults is not None else None)
             for i, mesh in enumerate(meshes)]
+        for e, mesh in zip(self.engines, meshes):
+            # the slot pool lives on its replica's device, beside the weights
+            e.cache = replicate_params(e.cache, mesh)
         self.meshes = meshes
         self.faults = faults
         self.health_latency_s = health_latency_s

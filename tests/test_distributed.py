@@ -33,6 +33,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced, build_model
+        from repro.launch.mesh import make_mesh
         from repro.models import sharding as shd
         from repro.optim import adamw_init
         from repro.train import make_train_step, train_state_init
@@ -48,7 +49,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         s_ref, m_ref = jax.jit(step)(train_state_init(params), batch)
         loss_ref = float(m_ref['loss'])
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         shd.set_global_mesh(mesh)
         NS = lambda t: jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), t,
@@ -80,6 +81,7 @@ def test_context_parallel_decode_matches_replicated():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced, build_model
+        from repro.launch.mesh import make_mesh
         from repro.models import sharding as shd
 
         cfg = reduced(get_config('qwen3-1.7b'))
@@ -91,7 +93,7 @@ def test_context_parallel_decode_matches_replicated():
         nxt = jnp.ones((1, 1), jnp.int32)
         ref, _ = model.decode_step(params, nxt, cache)
 
-        mesh = jax.make_mesh((8, 1), ('data', 'model'))
+        mesh = make_mesh((8, 1), ('data', 'model'))
         shd.set_global_mesh(mesh)
         NS = lambda t: jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), t,
@@ -113,6 +115,7 @@ def test_compressed_dp_training_converges_like_uncompressed():
     r = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, reduced, build_model
+        from repro.launch.mesh import make_mesh
         from repro.models import sharding as shd
         from repro.optim import error_feedback_init
         from repro.optim.schedules import constant_lr
@@ -122,7 +125,7 @@ def test_compressed_dp_training_converges_like_uncompressed():
         cfg = reduced(get_config('qwen3-1.7b'))
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = make_mesh((8,), ('data',))
         shd.set_global_mesh(None)
         batch = {'tokens': jax.random.randint(jax.random.PRNGKey(1),
                                               (8, 32), 0, cfg.vocab_size)}
@@ -152,6 +155,7 @@ def test_elastic_failure_remesh_resume():
     failure -> degraded mesh -> restore -> finish."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_ENABLE_COMPILATION_CACHE="false",  # tests keep it off
                PYTHONPATH=str(ROOT / "src"))
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "qwen3-1.7b",
@@ -174,12 +178,13 @@ def test_dryrun_cell_on_test_mesh():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced, build_model
+        from repro.launch.mesh import make_mesh
         from repro.models import sharding as shd
         from repro.launch.hlo_analysis import analyze
 
         cfg = reduced(get_config('olmoe-1b-7b'))
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         shd.set_global_mesh(mesh)
         NS = lambda t: jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), t,

@@ -25,7 +25,8 @@ def test_pod_loss_remesh_at_512():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced, build_model
-        from repro.launch.mesh import make_production_mesh, make_elastic_mesh
+        from repro.launch.mesh import (make_elastic_mesh, make_mesh,
+                                       make_production_mesh)
         from repro.models import sharding as shd
         from repro.optim.schedules import constant_lr
         from repro.train import (make_train_step, train_state_init,
@@ -58,8 +59,8 @@ def test_pod_loss_remesh_at_512():
             return state, float(m['loss'])
 
         # 2 pods of (data=4, model=4) = 32 chips (production topology)
-        mesh2 = jax.make_mesh((2, 4, 4), ('pod', 'data', 'model'),
-                              devices=jax.devices()[:32])
+        mesh2 = make_mesh((2, 4, 4), ('pod', 'data', 'model'),
+                          devices=jax.devices()[:32])
         state, loss2 = run_on(mesh2)
         save_checkpoint(ckdir, int(state.step), state)
 
@@ -97,6 +98,7 @@ def _make_runner(lm_zoo, ckpt_dir, *, ckpt_every=2, n_builders=3):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
     from repro.optim.schedules import constant_lr
     from repro.train import make_train_step, train_state_init
     from repro.train.elastic import ElasticConfig, ElasticRunner
@@ -104,7 +106,7 @@ def _make_runner(lm_zoo, ckpt_dir, *, ckpt_every=2, n_builders=3):
     cfg, model, params = lm_zoo("qwen3-1.7b")
     step = make_train_step(model, schedule=constant_lr(1e-3))
     builders = [
-        (lambda: jax.make_mesh((1,), ("data",))) for _ in range(n_builders)]
+        (lambda: make_mesh((1,), ("data",))) for _ in range(n_builders)]
 
     def make_step(mesh):
         return jax.jit(step)

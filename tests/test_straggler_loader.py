@@ -55,10 +55,10 @@ def test_summary_shape():
 
 
 def test_loader_reassign():
-    import jax
     from repro.data import ShardedLoader, SyntheticTokenDataset
+    from repro.launch.mesh import make_mesh
     ds = SyntheticTokenDataset(64, 8, seed=1)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     loader = ShardedLoader(
         lambda step, bs, shard, n: {"tokens": ds.batch(step, bs, shard, n)},
         global_batch=4, mesh=mesh, n_shards=4, shard=0)
