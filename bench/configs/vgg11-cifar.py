@@ -18,7 +18,6 @@ bit wherever they compute the same thing.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 
@@ -86,25 +85,7 @@ def deploy_event_calls(sizes: dict, batch: int) -> list:
     return calls
 
 
-def kd_backward_event_calls(sizes: dict, batch: int) -> list:
-    """(flops, bytes) of each event-skipped backward call one KD step of
-    ``batch`` images makes under ``fused_dense``, at the op's entry (the
-    ``ops.matmul`` custom-vjp backward): for every conv over spikes,
-    ``spike_matmul_dx`` (g @ W^T: g, W in, dx out) and ``spike_matmul_dw``
-    (x^T @ g: the spike operand, g in, dW out), all float32 as the
-    differentiable policy hands them."""
-    calls = []
-    for layer in plan(sizes)[1:]:
-        if layer[0] != "conv":
-            continue
-        _, cin, cout, h = layer
-        m, k, n = batch * h * h, 9 * cin, cout
-        flops, nbytes = 2.0 * m * k * n, 4.0 * (m * n + k * n + m * k)
-        calls += [(flops, nbytes), (flops, nbytes)]
-    return calls
-
-
-def snn_config(sizes: dict, head: str):
+def snn_config(sizes: dict):
     from repro.core.lif import LIFConfig
     from repro.core.quant import QuantConfig
     from repro.models import snn_cnn
@@ -117,7 +98,7 @@ def snn_config(sizes: dict, head: str):
                       surrogate=sizes["surrogate"],
                       alpha=sizes["surrogate_alpha"]),
         quant=QuantConfig(enabled=True, bits=sizes["quant_bits"]),
-        head=head)
+        head=sizes["deploy_head"])
 
 
 # ----------------------------------------------------------------- images
@@ -243,11 +224,11 @@ def deploy_reference(art, x, sizes: dict, lower: bool = False):
 
 
 def deploy_forward(sizes: dict):
-    """The timed call: ``snn_cnn.forward`` on the deployed artifact under
-    the deploy policy, jitted; returns (logits, per-layer spike counts)."""
+    """The timed call's body: ``snn_cnn.forward`` on the deployed artifact
+    under the deploy policy; returns (logits, per-layer spike counts)."""
     from repro.models import snn_cnn
 
-    cfg = snn_config(sizes, sizes["deploy_head"])
+    cfg = snn_config(sizes)
     n_maps = len(plan(sizes)) - 1
 
     def fwd(art, x):
@@ -257,283 +238,9 @@ def deploy_forward(sizes: dict):
                                               jnp.float32)
                                   for i in range(n_maps)])
 
-    return jax.jit(fwd)
+    return fwd
 
 
 def deploy_outputs(out):
     """(logits, per-layer spike counts) of one timed call's output."""
     return out
-
-
-# --------------------------------------------------------------- KD builder
-def teacher_config(sizes: dict):
-    from repro.models import ann_cnn
-
-    t = sizes["teacher"]
-    return ann_cnn.ANNCNNConfig(
-        arch=t["arch"], num_classes=sizes["num_classes"],
-        in_channels=sizes["in_channels"], image_size=sizes["image_size"],
-        width_mult=t["width_mult"])
-
-
-def _init_leaf(name: str, sd, key):
-    last = name.rsplit("/", 1)[-1]
-    if last in ("scale", "var"):
-        return jnp.ones(sd.shape, sd.dtype)
-    if last in ("bias", "mean", "b"):
-        return jnp.zeros(sd.shape, sd.dtype)
-    if name.endswith("fc/w"):                   # Xavier-uniform classifier
-        lim = math.sqrt(6.0 / (sd.shape[0] + sd.shape[1]))
-        return jax.random.uniform(key, sd.shape, sd.dtype, -lim, lim)
-    fan_in = math.prod(sd.shape[:-1])           # He-normal conv
-    return jax.random.normal(key, sd.shape, sd.dtype) * math.sqrt(2.0 / fan_in)
-
-
-def kd_variables(sizes: dict, key):
-    """(student, teacher) variables {"params", "state"} in the layouts of
-    ``snn_cnn.init`` and ``ann_cnn.init``, made in one jitted call: convs
-    He-normal, classifiers Xavier-uniform, BN at scale 1, bias 0, running
-    mean 0 and variance 1."""
-    from bench.common import path_name
-    from repro.models import ann_cnn, snn_cnn
-
-    shapes = jax.eval_shape(
-        lambda k: (snn_cnn.init(k, snn_config(sizes, sizes["train_head"])),
-                   ann_cnn.init(k, teacher_config(sizes))), key)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-
-    def gen(k):
-        keys = jax.random.split(k, len(flat))
-        return jax.tree_util.tree_unflatten(treedef, [
-            _init_leaf(path_name(p), sd, kk)
-            for (p, sd), kk in zip(flat, keys)])
-
-    return jax.jit(gen)(key)
-
-
-def kd_batches(sizes: dict, key, n: int, batch: int):
-    """n batches {"images", "labels"} made on the device from ``key``."""
-    out = []
-    for k in jax.random.split(key, n):
-        ki, kl = jax.random.split(k)
-        out.append({"images": images(sizes, ki, batch),
-                    "labels": jax.random.randint(kl, (batch,), 0,
-                                                 sizes["num_classes"])})
-    return out
-
-
-def kd_schedule(sizes: dict):
-    kd = sizes["kd"]
-
-    def lr(step):
-        t = jnp.clip(jnp.asarray(step, jnp.float32) / kd["schedule_steps"],
-                     0.0, 1.0)
-        return kd["lr"] * (0.1 + 0.9 * 0.5 * (1.0 + jnp.cos(jnp.pi * t)))
-    return lr
-
-
-def kd_step(sizes: dict, teacher: dict):
-    """The timed call: ``make_kd_train_step`` under the train policy,
-    jitted, with its carry builder. Returns (step, carry0 builder)."""
-    from repro.core.kd import KDConfig
-    from repro.models import ann_cnn, snn_cnn
-    from repro.optim import sgd_init
-    from repro.train import make_kd_train_step
-
-    scfg = snn_config(sizes, sizes["train_head"])
-    scfg = dataclasses.replace(scfg, quant=dataclasses.replace(
-        scfg.quant, enabled=False))
-    tcfg = teacher_config(sizes)
-    kd = sizes["kd"]
-
-    def teacher_apply(p, x):
-        return ann_cnn.apply({"params": p, "state": teacher["state"]}, x,
-                             tcfg, train=False)[0]
-
-    def student_apply(p, s, x, policy=None):
-        return snn_cnn.forward({"params": p, "state": s}, x, scfg,
-                               train=True, policy=policy)
-
-    step = jax.jit(make_kd_train_step(
-        student_apply, teacher_apply, teacher["params"],
-        kd=KDConfig(alpha=kd["alpha"], temperature=kd["temperature"]),
-        schedule=kd_schedule(sizes),
-        optimizer="sgd", momentum=kd["momentum"],
-        weight_decay=kd["weight_decay"], policy=sizes["train_policy"]))
-
-    def carry0(student):
-        return (student["params"], sgd_init(student["params"]),
-                student["state"])
-
-    return step, carry0
-
-
-def kd_flops_per_image(sizes: dict) -> float:
-    """Model FLOPs of one KD image: the student's forward and backward
-    (3x its convs and classifier: forward, input and weight gradients; the
-    first conv's input gradient is not needed and not counted) plus the
-    teacher's forward."""
-    f = 0.0
-    for layer in plan(sizes):
-        if layer[0] == "conv":
-            _, cin, cout, h = layer
-            fwd = 2.0 * conv_taps(h) * cin * cout
-            f += fwd * (2 if cin == sizes["in_channels"] else 3)
-        elif layer[0] == "head":
-            f += 3 * 2.0 * layer[1] * sizes["num_classes"]
-    return f + teacher_flops_per_image(sizes)
-
-
-def teacher_plan(sizes: dict) -> tuple:
-    """ResNet-18 with the CIFAR stem (one 3x3 conv, no pooling), four
-    stages of two basic blocks, 1x1 projection shortcuts where the shape
-    changes: (stem channels, [(cin, cout, stride, output size)])."""
-    t = sizes["teacher"]
-    c = lambda ch: max(8, int(ch * t["width_mult"]))  # noqa: E731
-    h, cin = sizes["image_size"], c(64)
-    blocks = []
-    for stage in range(4):
-        cout = c(64 * 2 ** stage)
-        for i in range(2):
-            stride = 2 if (stage > 0 and i == 0) else 1
-            h //= stride
-            blocks.append((cin, cout, stride, h))
-            cin = cout
-    return c(64), blocks
-
-
-def teacher_flops_per_image(sizes: dict) -> float:
-    """The teacher's forward FLOPs: convs, projections and classifier."""
-    stem, blocks = teacher_plan(sizes)
-    h = sizes["image_size"]
-    f = 2.0 * conv_taps(h) * sizes["in_channels"] * stem
-    for cin, cout, stride, ho in blocks:
-        f += 2.0 * (conv_taps(ho * stride, 3, stride) * cin
-                    + conv_taps(ho) * cout) * cout
-        if stride != 1 or cin != cout:
-            f += 2.0 * ho * ho * cin * cout
-    return f + 2.0 * blocks[-1][1] * sizes["num_classes"]
-
-
-# ------------------------------------------------------------ KD reference
-def _spike_fn(alpha: float):
-    @jax.custom_vjp
-    def spike(v):
-        return (v >= 0).astype(v.dtype)
-
-    def fwd(v):
-        return spike(v), v
-
-    def bwd(v, g):
-        return (g * alpha / (2.0 * (1.0 + (math.pi / 2.0 * alpha * v) ** 2)),)
-
-    spike.defvjp(fwd, bwd)
-    return spike
-
-
-def _lowered(x, lower: bool):
-    return x.astype(jnp.bfloat16).astype(jnp.float32) if lower else x
-
-
-def _conv_s(x, w, stride: int, lower: bool):
-    return jax.lax.conv_general_dilated(
-        _lowered(x, lower), _lowered(w, lower), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST,
-        preferred_element_type=jnp.float32)
-
-
-def _bn(x, p, s, train: bool, eps: float = 1e-5):
-    if train:
-        mean, var = x.mean(axis=(0, 1, 2)), x.var(axis=(0, 1, 2))
-    else:
-        mean, var = s["mean"], s["var"]
-    return (x - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _maxpool(x):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                 (1, 2, 2, 1), "VALID")
-
-
-def _student_logits(params, state, x, sizes, lower):
-    spike = _spike_fn(sizes["surrogate_alpha"])
-    for p, s, layer in zip(params, state, plan(sizes)):
-        if layer[0] == "conv":
-            cur = _bn(_conv_s(x, p["conv"]["w"], 1, lower), p["bn"], s["bn"],
-                      True)
-            x = spike(cur - sizes["lif_v_th"])
-        elif layer[0] == "pool":
-            x = _maxpool(x)
-        else:
-            pooled = x.mean(axis=(1, 2))
-            return jnp.matmul(_lowered(pooled, lower),
-                              _lowered(p["fc"]["w"], lower),
-                              precision=HIGHEST) + p["fc"]["b"]
-
-
-def _teacher_logits(tv, x, sizes, lower):
-    params, state = tv["params"], tv["state"]
-
-    def cbn(p, s, name, bn, x, stride):
-        return _bn(_conv_s(x, p[name]["w"], stride, lower), p[bn], s[bn],
-                   False)
-
-    relu = jax.nn.relu
-    x = relu(cbn(params[0], state[0], "conv", "bn", x, 1))
-    for p, s, (_, _, stride, _) in zip(params[1:-1], state[1:-1],
-                                       teacher_plan(sizes)[1]):
-        y = relu(cbn(p, s, "conv1", "bn1", x, stride))
-        y = cbn(p, s, "conv2", "bn2", y, 1)
-        sc = cbn(p, s, "conv_sc", "bn_sc", x, stride) if "conv_sc" in p \
-            else x
-        x = relu(y + sc)
-    fc = params[-1]["fc"]
-    return jnp.matmul(_lowered(x.mean(axis=(1, 2)), lower),
-                      _lowered(fc["w"], lower), precision=HIGHEST) + fc["b"]
-
-
-def _kd_loss(s_logits, t_logits, labels, alpha: float, temp: float):
-    logp = jax.nn.log_softmax(s_logits, axis=-1)
-    ce = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
-    p_t = jax.nn.softmax(t_logits / temp, axis=-1)
-    kl = jnp.sum(p_t * (jax.nn.log_softmax(t_logits / temp, axis=-1)
-                        - jax.nn.log_softmax(s_logits / temp, axis=-1)),
-                 axis=-1)
-    return (1.0 - alpha) * ce + alpha * jnp.mean(kl) * temp * temp
-
-
-@functools.partial(jax.jit, static_argnames=("sz", "lower"))
-def _kd_ref(student, teacher, batches, sz: tuple, lower: bool):
-    sizes = _thaw(sz)
-    kd = sizes["kd"]
-    lr_fn = kd_schedule(sizes)
-    params, state = student["params"], student["state"]
-    buf = jax.tree_util.tree_map(jnp.zeros_like, params)
-    losses, grad0 = [], None
-    for i, b in enumerate(batches):
-        t_logits = jax.lax.stop_gradient(
-            _teacher_logits(teacher, b["images"], sizes, lower))
-
-        def loss_fn(p):
-            return _kd_loss(_student_logits(p, state, b["images"], sizes,
-                                            lower),
-                            t_logits, b["labels"], kd["alpha"],
-                            kd["temperature"])
-
-        loss, g = jax.value_and_grad(loss_fn)(params)
-        losses.append(loss)
-        grad0 = g if grad0 is None else grad0
-        lr = lr_fn(i)
-        buf = jax.tree_util.tree_map(
-            lambda m, gg, p: kd["momentum"] * m + gg
-            + kd["weight_decay"] * p, buf, g, params)
-        params = jax.tree_util.tree_map(lambda p, m: p - lr * m, params, buf)
-    return jnp.stack(losses), grad0, params
-
-
-def kd_reference(student, teacher, batches, sizes: dict, lower: bool = False):
-    """The reference's first len(batches) KD steps from ``student``:
-    (losses, the first step's gradient, the parameters after the last
-    step). ``lower`` rounds every conv and matmul operand to bfloat16 (the
-    control)."""
-    return _kd_ref(student, teacher, batches, _freeze(sizes), lower)

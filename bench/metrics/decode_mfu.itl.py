@@ -4,7 +4,7 @@ processed (one per live slot decoded, plus the valid prompt tokens of the
 prefill chunks they ran) over their summed host spans times the chip's
 bf16 peak. Idle slots of the pool-wide decode and chunk padding do not
 count."""
-LAYER = "model step (models/lm.py, models/snn_cnn.py, train/trainer.py)"
+LAYER = "model step (models/lm.py, models/snn_cnn.py)"
 SOURCE = "host_clock"
 MOVES = "itl_p95_ms"
 UNIT = "%"

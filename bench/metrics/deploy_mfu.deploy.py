@@ -1,11 +1,11 @@
 """Model FLOP utilisation of deployed inference over the traced window: the
 configuration's model FLOPs per image times the images of every deployed
 forward the trace holds, over the window times the chip's bf16 peak."""
-LAYER = "model step (models/lm.py, models/snn_cnn.py, train/trainer.py)"
+LAYER = "model step (models/lm.py, models/snn_cnn.py)"
 SOURCE = "device_trace"
 MOVES = "deploy_images_per_s"
 UNIT = "%"
-PROGRAM = "jit_fwd"
+PROGRAM = "jit_classify_batch"
 
 
 def read(run):

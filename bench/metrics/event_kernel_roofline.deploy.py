@@ -9,7 +9,7 @@ LAYER = "kernels (kernels/* via repro.ops)"
 SOURCE = "device_trace"
 MOVES = "deploy_images_per_s"
 UNIT = "%"
-PROGRAM = "jit_fwd"
+PROGRAM = "jit_classify_batch"
 KERNELS = r"%fused_pe_pallas\b"
 
 

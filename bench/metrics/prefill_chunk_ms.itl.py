@@ -1,8 +1,10 @@
 """Device time per call of the engine's prefill-chunk program
-(``jit_prefill_chunk``), from the trace's ``XLA Modules`` line."""
-LAYER = "model step (models/lm.py, models/snn_cnn.py, train/trainer.py)"
+(``jit_prefill_chunk``), from the trace's ``XLA Modules`` line. A chunk
+runs inside the engine step that then decodes, so the steps that carry
+one are the long gaps between tokens."""
+LAYER = "model step (models/lm.py, models/snn_cnn.py)"
 SOURCE = "device_trace"
-MOVES = "ttft_p95_ms"
+MOVES = "itl_p95_ms"
 UNIT = "ms"
 PROGRAM = "prefill_chunk"
 

@@ -27,6 +27,7 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -141,6 +142,8 @@ class Run:
     _trace_dir: Optional[str] = None
     _window_ann: Any = None
     _compiles: list = dataclasses.field(default_factory=list)
+    _gc: list = dataclasses.field(default_factory=list)
+    _longest: tuple = (0.0, 0.0, 0.0)  # (seconds, CPU seconds, start)
 
     @property
     def params(self) -> dict:
@@ -160,7 +163,7 @@ class Run:
 
             ann = jax.profiler.TraceAnnotation(f"{tracemod.SPAN_PREFIX}{name}")
             ann.__enter__()
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.process_time()
         try:
             yield
         finally:
@@ -168,6 +171,8 @@ class Run:
             if ann is not None:
                 ann.__exit__(None, None, None)
             self.spans.append((name, t0, t1, self._tracing))
+            if t1 - t0 > self._longest[0]:
+                self._longest = (t1 - t0, time.process_time() - c0, t0)
 
     def trace_plan(self) -> tuple[float, float]:
         """Where in the window the trace runs, in seconds from its start:
@@ -215,6 +220,22 @@ class Run:
         """Names of the programs compiled or loaded since ``t0``."""
         return [name for t, name in self._compiles if t >= t0]
 
+    def stalls_since(self, t0: float) -> dict:
+        """What could have held the host since ``t0``: the garbage
+        collector's pauses, the harness spans over 100 ms, and the longest
+        span with the CPU time the process spent in it (near its length:
+        the host computed; near 0: it waited)."""
+        gcs = [d for t, d, _ in self._gc if t >= t0]
+        spans = [b - a for _, a, b, _ in self.spans if a >= t0]
+        longest, cpu, at = self._longest
+        return {"gc_pauses": len(gcs),
+                "gc_max_ms": 1e3 * max(gcs, default=0.0),
+                "gc_total_ms": 1e3 * sum(gcs),
+                "spans_over_100ms": sum(d > 0.1 for d in spans),
+                "span_max_ms": 1e3 * max(spans, default=0.0),
+                "span_max_cpu_ms": 1e3 * cpu if at >= t0 else 0.0,
+                "span_max_at_s": at - t0 if at >= t0 else 0.0}
+
 
 def _watch_compiles(run: Run) -> None:
     import jax
@@ -226,6 +247,20 @@ def _watch_compiles(run: Run) -> None:
             seen.append((time.perf_counter(), kw.get("fun_name")))
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
+
+
+def _watch_gc(run: Run) -> None:
+    pauses = run._gc                  # (start, seconds, generation)
+    started = []
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            started.append(time.perf_counter())
+        elif started:
+            t0 = started.pop()
+            pauses.append((t0, time.perf_counter() - t0, info["generation"]))
+
+    gc.callbacks.append(on_gc)
 
 
 def device_info(devices: list, trace_summary=None) -> dict:
@@ -266,13 +301,6 @@ def prepare(cell: str, seed: int, seconds: float, trace: bool, *,
         sizes = dict(sizes, **sizes_override)
     kind = traffic(wl["traffic"])
     devices = find_devices(int(wl["chips"]), require_tpu)
-    if "matmul_precision" in sizes:
-        # the precision the configuration states, where JAX's default on
-        # the platform would compute float32 matmuls in less
-        import jax
-
-        jax.config.update("jax_default_matmul_precision",
-                          sizes["matmul_precision"])
     if require_tpu:
         from repro.launch.compile_cache import use_compile_cache
 
@@ -286,7 +314,17 @@ def prepare(cell: str, seed: int, seconds: float, trace: bool, *,
               else {"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0},
               devices=devices)
     _watch_compiles(run)
+    _watch_gc(run)
     return kind, run
+
+
+def settle() -> None:
+    """End of set-up: collect what set-up left behind, then freeze every
+    surviving object, so that a collection in the window scans only what
+    the window made and not the whole heap that JAX and set-up built.
+    ``gc.unfreeze()`` after the window gives them back to the collector."""
+    gc.collect()
+    gc.freeze()
 
 
 def run_cell(cell: str, seed: int, seconds: float, trace: bool,
@@ -295,9 +333,12 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     spec = benchmark_spec()
     kind, run = prepare(cell, seed, seconds, trace, **overrides)
     state = run.state = kind.setup(run)
+    settle()
     setup_s = time.perf_counter() - T_START
     t_window = time.perf_counter()
     kind.measure(run, state)
+    gc.unfreeze()
+    run.note(**run.stalls_since(t_window))
     run.trace_reduce()
     compiles = run.compiles_since(t_window)
     dev = device_info(run.devices, run.summary)

@@ -64,10 +64,6 @@ def test_vgg_counts_by_hand():
     assert len(calls) == 7
     m, k, n = 1024 * 16 * 16, 9 * 64, 128
     assert calls[0] == (2.0 * m * k * n, m * k / 8 + k * n * 4 + n * 4 + m * n / 8)
-    bwd = mod.kd_backward_event_calls(sizes, 256)
-    m = 256 * 16 * 16
-    assert len(bwd) == 14
-    assert bwd[0] == (2.0 * m * k * n, 4.0 * (m * n + k * n + m * k))
 
 
 def test_vgg_deploy_count_matches_xla_on_the_reference():
@@ -79,17 +75,6 @@ def test_vgg_deploy_count_matches_xla_on_the_reference():
     sz = mod._freeze(sizes)
     xla = _flops(lambda a, x: mod._deploy_ref(a, x, sz, False), art, x)
     counted = 16 * mod.deploy_flops_per_image(sizes)
-    assert counted <= xla <= 1.1 * counted
-
-
-def test_teacher_count_matches_xla():
-    mod, sizes = R.config("vgg11-cifar")
-    sizes = dict(sizes, teacher={"arch": "resnet18", "width_mult": 0.25})
-    student, teacher = mod.kd_variables(sizes, jax.random.PRNGKey(0))
-    x = jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.float32)
-    xla = _flops(lambda t, x: mod._teacher_logits(t, x, sizes, False),
-                 teacher, x)
-    counted = 8 * mod.teacher_flops_per_image(sizes)
     assert counted <= xla <= 1.1 * counted
 
 

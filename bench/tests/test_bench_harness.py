@@ -124,3 +124,53 @@ print("clean")
                        capture_output=True, text=True, timeout=300)
     assert p.returncode == 0, p.stderr
     assert "clean" in p.stdout
+
+
+def test_set_up_ends_frozen_and_the_window_records_its_stalls():
+    import gc
+    import time
+
+    _, run = R.prepare("vgg11-cifar.deploy", 3, 1.0, False,
+                       require_tpu=False)
+    R.settle()
+    try:
+        assert gc.get_freeze_count() > 0
+        t0 = time.perf_counter()
+        with run.span("call"):
+            gc.collect()
+    finally:
+        gc.unfreeze()
+    assert gc.get_freeze_count() == 0
+    s = run.stalls_since(t0)
+    assert s["gc_pauses"] >= 1
+    assert 0 < s["gc_max_ms"] <= s["gc_total_ms"]
+    assert s["span_max_ms"] >= s["gc_max_ms"]
+    assert run.stalls_since(time.perf_counter())["gc_pauses"] == 0
+
+
+def test_sweep_row_counts_the_backlog_and_both_halves():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("bench_sweep",
+                                                  R.BENCH / "sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    Req = serve().Req
+    reqs = [Req(due=d, prompt=np.zeros(4, np.int32), max_new=2, uid=i,
+                first=f)
+            for i, (d, f) in enumerate([(0.0, 0.1), (1.0, 1.3), (6.0, 6.5),
+                                        (8.0, 11.0), (9.0, float("nan"))])]
+
+    class St:
+        pass
+
+    st = St()
+    st.reqs, st.drain_end = reqs, 12.0
+    st.steps = [{"dt": 0.04}, {"dt": 0.06}]
+    r = sweep.row(2.0, st, 10.0)
+    assert r["due"] == 5
+    assert r["backlog_at_close"] == 2           # first after 10 s, and none
+    assert r["ttft_p50_first_half_ms"] == pytest.approx(200.0)
+    assert r["ttft_p50_second_half_ms"] == pytest.approx(3000.0)
+    assert r["ttft_p50_ms"] == pytest.approx(500.0)
+    assert r["step_mean_ms"] == pytest.approx(50.0)

@@ -44,3 +44,19 @@ def test_a_token_altered_where_produced_is_not_correct(monkeypatch):
     out = R.run_cell(CELL, 5, 3.0, False, require_tpu=False,
                      sizes_override=TINY, params_override=LOAD)
     assert out["correct"] is False
+
+
+def test_sweep_runs_each_rate_on_a_fresh_engine():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("bench_sweep",
+                                                  R.BENCH / "sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    rows = sweep.sweep(CELL, 2**31 + 17, 2.0, [1.0, 4.0], 20.0,
+                       require_tpu=False, sizes_override=TINY,
+                       params_override=LOAD)
+    assert [r["rate_per_s"] for r in rows] == [1.0, 4.0]
+    assert [r["due"] for r in rows] == [2, 8]
+    for r in rows:
+        assert r["ttft_p95_ms"] >= r["ttft_p50_ms"] > 0

@@ -1,12 +1,14 @@
-"""Offline batch inference through the deployed artifact: batches of
-``batch`` images classified back to back by the configuration's jitted
-deployed forward, for the whole window.
+"""Offline batch inference through the deployed artifact: a held data set
+classified in calls of ``batch`` images, back to back, for the whole
+window.
 
-The images are a pool of ``pool_batches`` batches made on the device from
-the seed; call i classifies pool batch i mod pool_batches. At most two calls
-are in flight: a call is dispatched, then the one before it is waited for.
-The window ends at the completion of the first call that ends after
-``--seconds``; the rate is the images of all completed calls over that time.
+The data set (``images`` of them) is made on the device from the seed in
+one call and stays there, as an offline job holds its input. Call i
+classifies batch i mod (images // batch) of it, sliced inside the jitted
+call. At most two calls are in flight: a call is dispatched, then the one
+before it is waited for. The window ends at the completion of the first
+call that ends after ``--seconds``; the rate is the images of all
+completed calls over that time.
 """
 from __future__ import annotations
 
@@ -21,27 +23,46 @@ from bench.common import (STREAM_DATA, STREAM_SAMPLE, STREAM_WEIGHTS,
 
 @dataclasses.dataclass
 class State:
-    forward: object                   # jitted (artifact, images) -> outputs
+    classify: object                  # jitted (artifact, data, i) -> outputs
     artifact: list
-    pool: list                        # device image batches
+    data: object                      # [images, H, W, C] on the device
+    batch: int
     outputs: list = dataclasses.field(default_factory=list)
     calls: int = 0
     window: float = 0.0
+
+    @property
+    def batches(self) -> int:
+        return self.data.shape[0] // self.batch
 
 
 def setup(run) -> State:
     import jax
 
     cfgm, sizes, p = run.config, run.sizes, run.params
-    keys = jax.random.split(jax_key(run.seed, STREAM_DATA),
-                            p["pool_batches"] + 1)
-    pool = [cfgm.images(sizes, k, p["batch"]) for k in keys[1:]]
-    calib = cfgm.images(sizes, keys[0], p["calib_images"])
+    k_data, k_calib = jax.random.split(jax_key(run.seed, STREAM_DATA))
+    data = cfgm.images(sizes, k_data, p["images"])
+    calib = cfgm.images(sizes, k_calib, p["calib_images"])
     art = cfgm.make_artifact(sizes, jax_key(run.seed, STREAM_WEIGHTS), calib)
-    fwd = cfgm.deploy_forward(sizes)
-    for x in pool[:2]:
-        jax.block_until_ready(fwd(art, x))
-    return State(fwd, art, pool)
+    forward, batch = cfgm.deploy_forward(sizes), p["batch"]
+
+    def classify_batch(art, data, i):
+        x = jax.lax.dynamic_slice_in_dim(data, i * batch, batch)
+        return forward(art, x)
+
+    classify = jax.jit(classify_batch)
+    st = State(classify, art, data, batch)
+    for i in range(2):
+        jax.block_until_ready(classify(art, data, i))
+    return st
+
+
+def images_of(st: State, i: int):
+    """The images call i classified."""
+    import jax
+
+    return jax.lax.dynamic_slice_in_dim(st.data, (i % st.batches) * st.batch,
+                                        st.batch)
 
 
 def measure(run, st: State) -> None:
@@ -54,7 +75,7 @@ def measure(run, st: State) -> None:
         now = time.perf_counter() - t0
         run.trace_tick(now)
         with run.span("call"):
-            cur = st.forward(st.artifact, st.pool[i % len(st.pool)])
+            cur = st.classify(st.artifact, st.data, i % st.batches)
             if prev is not None:
                 jax.block_until_ready(prev)
         outs.append(cur)
@@ -102,8 +123,8 @@ def readings(run, st: State) -> dict:
     err = mis = 0.0
     idx = _sample(run, st)
     for i in idx:
-        ref = run.config.deploy_reference(
-            st.artifact, st.pool[i % len(st.pool)], run.sizes)
+        ref = run.config.deploy_reference(st.artifact, images_of(st, i),
+                                          run.sizes)
         e, m = _errors(run.config.deploy_outputs(st.outputs[i]), ref)
         err, mis = max(err, e), max(mis, m)
     run.note(check_calls=len(idx),
@@ -116,7 +137,7 @@ def controls(run, st: State) -> dict:
     the same sampled calls' images."""
     err = mis = 0.0
     for i in _sample(run, st):
-        x = st.pool[i % len(st.pool)]
+        x = images_of(st, i)
         e, m = _errors(
             run.config.deploy_reference(st.artifact, x, run.sizes, True),
             run.config.deploy_reference(st.artifact, x, run.sizes))
