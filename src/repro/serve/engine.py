@@ -44,9 +44,16 @@ depth elasticity in software:
     blocking.
 
 Sampling: greedy or temperature (per request).
+
+Observability: every ``step()`` appends one record to a bounded tick log
+(``last_tick``; aggregated in ``stats()``) with the nanoseconds of each
+phase that ran and the number of device->host syncs the tick made, and runs
+each phase inside a ``serve.<phase>`` span of the JAX profiler, on the same
+clock as the device's events when a trace is active (see ``step``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
@@ -62,6 +69,9 @@ from ..core.events import pad_lane_mask
 from .faults import FaultPlan, ReplicaFailure
 
 Array = jax.Array
+
+# Profiler span prefix of the engine's phases (see ``Engine.step``).
+SPAN_PREFIX = "serve."
 
 # Jitted engine step functions shared across Engine instances of the same
 # (model class, config): a process serving N replicas — or a test suite
@@ -141,6 +151,7 @@ class Request:
     # a quarantine replay regenerates the greedy stream from scratch but
     # only pushes tokens PAST this mark — at-most-once delivery
     enqueued_t: float = 0.0
+    admitted_t: float = 0.0             # first taken from the admission FIFO
     first_token_t: float = 0.0
     finished_t: float = 0.0
     enqueued_tick: int = 0
@@ -251,14 +262,17 @@ class Engine:
                               and cfg.spike_stats_every > 0)
         self._spike_log: list[dict] = []
         self._tick = 0
-        # elastic-FIFO telemetry: occupancy high-water marks + tick latency
+        # elastic-FIFO telemetry: occupancy high-water marks
         self._queue_hwm = 0
         self._prefill_fifo_hwm = 0
         self._out_fifo_hwm = 0
         self._stall_ticks = 0
         self._prefill_chunks = 0
-        # rolling window: stats() percentiles stay O(window), memory bounded
-        self._tick_wall: deque = deque(maxlen=4096)
+        # tick log, one record per step() call (see ``step``); a rolling
+        # window, so stats() percentiles stay O(window) and memory bounded
+        self._ticks: deque = deque(maxlen=4096)
+        self._rec: dict = {}                # the record of the running step
+        self._syncs = 0                     # device->host syncs ever made
         # self-healing state + counters
         self._tokens_emitted = 0
         self._cancelled = 0
@@ -424,6 +438,8 @@ class Engine:
         chunked = self.cfg.prefill_chunk > 0
         while self.queue and self.free_slots:
             req = self.queue.popleft()
+            if not req.admitted_t:          # a replay keeps its first
+                req.admitted_t = time.time()
             slot = self.free_slots.pop()
             req.slot = slot
             req.status = STATUS_PREFILL
@@ -437,9 +453,10 @@ class Engine:
         pad_len = self._bucket_len(s)
         toks = np.zeros((1, pad_len), np.int32)
         toks[0, :s] = req.prompt        # right-pad (causal: pads inert)
-        logits, cache = self._prefill(self.params, jnp.asarray(toks))
-        self._write_slot(slot, cache)
-        self._activate(req, slot, logits[0, s - 1])
+        with self._phase("prefill", uid=req.uid, chunk=0):
+            logits, cache = self._prefill(self.params, jnp.asarray(toks))
+            self._write_slot(slot, cache)
+            self._activate(req, slot, logits[0, s - 1])
 
     def _admit_chunked(self, req: Request, slot: int) -> None:
         s = len(req.prompt)
@@ -694,9 +711,39 @@ class Engine:
 
     def _sample(self, logits: Array, req: Request) -> int:
         if req.temperature <= 0.0:
-            return int(jnp.argmax(logits))
+            return int(self._sync(jnp.argmax(logits)))
         self._rng, k = jax.random.split(self._rng)
-        return int(jax.random.categorical(k, logits / req.temperature))
+        return int(self._sync(
+            jax.random.categorical(k, logits / req.temperature)))
+
+    # ------------------------------------------------------ tick telemetry
+    def _sync(self, x: Array, fetch: bool = True) -> Any:
+        """Every point where the engine waits for the device goes through
+        here, so the tick log counts each one: ``fetch`` copies ``x`` to
+        the host as a NumPy array, else it only waits until ``x`` is
+        computed and returns it."""
+        self._syncs += 1
+        return np.asarray(x) if fetch else jax.block_until_ready(x)
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, annotation=jax.profiler.TraceAnnotation,
+               **meta):
+        """Run one phase of the current tick inside a ``serve.<name>``
+        profiler span (``meta`` rides on the span) and add its host
+        nanoseconds to the tick's record. While no trace is active a
+        phase costs a few microseconds of host time."""
+        t0 = time.perf_counter_ns()
+        try:
+            with annotation(SPAN_PREFIX + name, **meta):
+                yield
+        finally:
+            ph = self._rec["phases"]
+            ph[name] = ph.get(name, 0) + time.perf_counter_ns() - t0
+
+    @property
+    def last_tick(self) -> Optional[dict]:
+        """The newest tick record (see ``step``); None before the first."""
+        return self._ticks[-1] if self._ticks else None
 
     # ------------------------------------------------------------------ tick
     def _stalled_slots(self) -> set:
@@ -721,16 +768,56 @@ class Engine:
     def step(self) -> int:
         """One engine tick: admit, drain up to ``prefill_chunks_per_tick``
         chunks from the prefill FIFO, then one pool decode for all live,
-        un-stalled slots. Returns number of live sequences."""
+        un-stalled slots. Returns number of live sequences.
+
+        Every call appends one record to the tick log (``last_tick``), also
+        a call that returns early or raises: ``tick`` (the engine tick it
+        ran at), ``live`` (slots that sampled a token from the decode),
+        ``decoded``, ``chunks`` (prefill chunks run), ``syncs`` (device->host
+        syncs: the decode's wait, one per sampled token, one per packed
+        pool that spike telemetry fetches, one per guard verdict) and
+        ``phases`` (host nanoseconds of each phase that ran). Each phase
+        runs in a profiler span named ``serve.<phase>``, nested in
+        ``serve.step`` (a step annotation whose ``step_num`` is ``tick``):
+
+        * ``admit``: the deadline sweep and admission (a blocking prefill
+          nests its ``prefill`` span here);
+        * ``prefill``: one chunk (upload, dispatch, last-logit slice; on
+          the final chunk the slot write and the first token's sample),
+          with the request's ``uid`` and ``chunk`` index as metadata;
+        * ``decode``: token and length uploads, the pool decode's dispatch
+          and the wait for its logits;
+        * ``guard``: fault injection and the integrity scan;
+        * ``spike_stats``: packed spike telemetry;
+        * ``sample``: stall restore, quarantine, then per slot the sample,
+          emit, finish and slot release."""
+        rec = self._rec = {"tick": self._tick, "live": 0, "decoded": False,
+                           "chunks": 0, "syncs": 0, "phases": {}}
+        syncs0, chunks0 = self._syncs, self._prefill_chunks
+        try:
+            with self._phase("step", jax.profiler.StepTraceAnnotation,
+                             step_num=rec["tick"]):
+                return self._step(rec)
+        finally:
+            rec["syncs"] = self._syncs - syncs0
+            rec["chunks"] = self._prefill_chunks - chunks0
+            self._ticks.append(rec)
+
+    def _step(self, rec: dict) -> int:
         if self.faults is not None and self.faults.die_due(self._tick):
             raise ReplicaFailure(
                 f"injected replica death at tick {self._tick}")
-        self._deadline_sweep()
-        self._admit()
+        with self._phase("admit"):
+            self._deadline_sweep()
+            self._admit()
         if self.cfg.prefill_chunk > 0:
             budget = max(1, self.cfg.prefill_chunks_per_tick)
             while budget > 0 and self.prefill_fifo:
-                if self._prefill_step(self.prefill_fifo[0]):
+                job = self.prefill_fifo[0]
+                with self._phase("prefill", uid=job.req.uid,
+                                 chunk=job.done // self._chunk_size()):
+                    finished = self._prefill_step(job)
+                if finished:
                     self.prefill_fifo.popleft()
                 budget -= 1
         if not self.active:
@@ -740,60 +827,70 @@ class Engine:
         if stalled and len(stalled) == len(self.active):
             self._stall_ticks += 1
             return len(self.active)     # every consumer is backed up
-        toks = np.zeros((self.cfg.max_slots, 1), np.int32)
-        for slot, req in self.active.items():
-            toks[slot, 0] = req.out[-1]
-        # per-slot length vector: every slot attends exactly its own prefix
-        self.cache["len"] = jnp.asarray(self.slot_len, jnp.int32)
-        prev_layers = self.cache["layers"] if stalled else None
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode(self.params, jnp.asarray(toks),
-                                          self.cache)
-        logits = jax.block_until_ready(logits)
-        self._tick_wall.append(time.perf_counter() - t0)
-        if self.faults is not None:
-            # injected corruption lands AFTER the decode, BEFORE the guard
-            # — the guard must catch it before a token is sampled from it
-            logits = self._inject_faults(logits)
-        bad = set()
-        if self.cfg.integrity_every > 0 \
-                and self._tick % self.cfg.integrity_every == 0:
-            self._guard_scans += 1
-            bad_num, bad_pack = self._integrity_verdict(logits)
-            bad_num, bad_pack = np.asarray(bad_num), np.asarray(bad_pack)
-            bad = {s for s in self.active
-                   if bad_num[s] or bad_pack[s]}
-            reasons = {s: ("packed_invariant" if bad_pack[s]
-                           else "non_finite") for s in bad}
+        with self._phase("decode"):
+            toks = np.zeros((self.cfg.max_slots, 1), np.int32)
+            for slot, req in self.active.items():
+                toks[slot, 0] = req.out[-1]
+            # per-slot length vector: every slot attends exactly its own
+            # prefix
+            self.cache["len"] = jnp.asarray(self.slot_len, jnp.int32)
+            prev_layers = self.cache["layers"] if stalled else None
+            logits, self.cache = self._decode(self.params, jnp.asarray(toks),
+                                              self.cache)
+            logits = self._sync(logits, fetch=False)
+        rec["decoded"] = True
+        scan = (self.cfg.integrity_every > 0
+                and self._tick % self.cfg.integrity_every == 0)
+        bad, reasons = set(), {}
+        if self.faults is not None or scan:
+            with self._phase("guard"):
+                if self.faults is not None:
+                    # injected corruption lands AFTER the decode, BEFORE
+                    # the guard — the guard must catch it before a token
+                    # is sampled from it
+                    logits = self._inject_faults(logits)
+                if scan:
+                    self._guard_scans += 1
+                    bad_num, bad_pack = map(
+                        self._sync, self._integrity_verdict(logits))
+                    bad = {s for s in self.active
+                           if bad_num[s] or bad_pack[s]}
+                    reasons = {s: ("packed_invariant" if bad_pack[s]
+                                   else "non_finite") for s in bad}
         if self._track_spikes and self._tick % self.cfg.spike_stats_every == 0:
-            self._record_spike_step(sorted(self.active.keys()))
-        if stalled:
-            self._stall_ticks += 1
-            for slot in stalled:
-                # exact stall (greedy): state row rolls back, same token
-                # re-fed next tick recomputes the identical step once the
-                # FIFO drains; temperature sampling is only reproducible up
-                # to the shared RNG stream's consumption order
-                self._restore_slot(slot, prev_layers)
-        for slot in sorted(bad):
-            # quarantine BEFORE sampling: no token leaves a poisoned slot
-            self._quarantine(slot, reasons[slot])
-        done_slots = []
-        for slot, req in list(self.active.items()):
-            if slot in stalled:
-                continue
-            tok = self._sample(logits[slot], req)
-            self._emit(req, tok)
-            self.slot_len[slot] += 1
-            hit_eos = req.eos_id is not None and tok == req.eos_id
-            if hit_eos or len(req.out) >= req.max_new \
-                    or self.slot_len[slot] >= self.cfg.max_len - 1:
-                self._finish(req, STATUS_DONE)
-                done_slots.append(slot)
-        for slot in done_slots:
-            del self.active[slot]
-            self.slot_len[slot] = 0
-            self.free_slots.append(slot)
+            with self._phase("spike_stats"):
+                self._record_spike_step(sorted(self.active.keys()))
+        with self._phase("sample"):
+            if stalled:
+                self._stall_ticks += 1
+                for slot in stalled:
+                    # exact stall (greedy): state row rolls back, same
+                    # token re-fed next tick recomputes the identical step
+                    # once the FIFO drains; temperature sampling is only
+                    # reproducible up to the shared RNG stream's
+                    # consumption order
+                    self._restore_slot(slot, prev_layers)
+            for slot in sorted(bad):
+                # quarantine BEFORE sampling: no token leaves a poisoned
+                # slot
+                self._quarantine(slot, reasons[slot])
+            done_slots = []
+            for slot, req in list(self.active.items()):
+                if slot in stalled:
+                    continue
+                tok = self._sample(logits[slot], req)
+                rec["live"] += 1
+                self._emit(req, tok)
+                self.slot_len[slot] += 1
+                hit_eos = req.eos_id is not None and tok == req.eos_id
+                if hit_eos or len(req.out) >= req.max_new \
+                        or self.slot_len[slot] >= self.cfg.max_len - 1:
+                    self._finish(req, STATUS_DONE)
+                    done_slots.append(slot)
+            for slot in done_slots:
+                del self.active[slot]
+                self.slot_len[slot] = 0
+                self.free_slots.append(slot)
         return len(self.active)
 
     def pending(self) -> bool:
@@ -861,20 +958,17 @@ class Engine:
             return
         n_units = (self.model.cfg.n_heads *
                    self.model.cfg.resolved_head_dim)
+        # one fetch per packed word pool, through the counted sync
+        pools = [self._sync(leaf)[:, live_slots]
+                 for leaf in jax.tree_util.tree_leaves(self.cache["layers"])
+                 if leaf.dtype == jnp.int32 and leaf.ndim == 5]
         spikes = packed_b = units = 0
-        for leaf in jax.tree_util.tree_leaves(self.cache["layers"]):
-            if leaf.dtype != jnp.int32 or leaf.ndim != 5:
-                continue                    # only the packed word pools
-            sel = np.asarray(leaf)[:, live_slots]
+        nz_words = blk_groups = blk_active = occ_words = 0
+        for sel in pools:
             spikes += int(np.unpackbits(
                 np.ascontiguousarray(sel).view(np.uint8)).sum())
             packed_b += sel.size * 4
             units += sel.shape[0] * len(live_slots) * n_units
-        nz_words = blk_groups = blk_active = occ_words = 0
-        for leaf in jax.tree_util.tree_leaves(self.cache["layers"]):
-            if leaf.dtype != jnp.int32 or leaf.ndim != 5:
-                continue
-            sel = np.asarray(leaf)[:, live_slots]
             nz = (sel != 0).reshape(-1, sel.shape[-1])
             # group word columns into 128-column (4-word) metadata blocks:
             # the k-axis granularity of the gated kernels' vld/occ maps
@@ -944,13 +1038,27 @@ class Engine:
                "prefill_fifo_hwm": self._prefill_fifo_hwm,
                "out_fifo_hwm": self._out_fifo_hwm,
                "stall_ticks": self._stall_ticks}
-        if self._tick_wall:
-            tw = np.asarray(self._tick_wall)
+        # tick log: the decode phase keeps the decode_tick_* keys; every
+        # phase's p50/p99, and the syncs of the ticks that decoded
+        decode_s = [r["phases"]["decode"] / 1e9 for r in self._ticks
+                    if "decode" in r["phases"]]
+        if decode_s:
             out.update({
-                "decode_ticks": len(tw),
-                "decode_tick_p50_s": float(np.percentile(tw, 50)),
-                "decode_tick_p99_s": float(np.percentile(tw, 99)),
-                "decode_tick_max_s": float(tw.max())})
+                "decode_ticks": len(decode_s),
+                "decode_tick_p50_s": float(np.percentile(decode_s, 50)),
+                "decode_tick_p99_s": float(np.percentile(decode_s, 99)),
+                "decode_tick_max_s": float(max(decode_s)),
+                "host_syncs_per_tick_mean": float(np.mean(
+                    [r["syncs"] for r in self._ticks if r["decoded"]]))})
+        by_phase: dict = {}
+        for r in self._ticks:
+            for name, ns in r["phases"].items():
+                by_phase.setdefault(name, []).append(ns / 1e6)
+        if by_phase:
+            out["phase_ms"] = {
+                name: {"p50": float(np.percentile(ms, 50)),
+                       "p99": float(np.percentile(ms, 99))}
+                for name, ms in by_phase.items()}
         if self._spike_log:
             rate = float(np.mean([e["spike_rate"] for e in self._spike_log]))
             pb = float(np.mean([e["packed_bytes"] for e in self._spike_log]))

@@ -13,6 +13,7 @@ The decisive tests:
     ticks-to-first-token at a full queue).
 """
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -247,3 +248,131 @@ def test_qk_spiking_engine_stateless_cache(lm_zoo):
                       max_slots=2, max_len=32, prefill_chunk=4)
     assert blocking == chunked
     assert len(blocking[0]) == 4
+
+
+# ------------------------------------------------ tick log and phase spans
+PHASES = {"serve.step", "serve.admit", "serve.prefill", "serve.decode",
+          "serve.spike_stats", "serve.sample"}
+
+
+def _packed_engine(lm_zoo, **cfg_kw):
+    """A tiny spiking engine under ``fused_packed``: one packed word pool,
+    spike telemetry every tick unless ``spike_stats_every`` says else."""
+    cfg, model, params = lm_zoo("qwen3-1.7b", spiking=True,
+                                attention_kind="qk_spiking")
+    kw = dict(max_slots=2, max_len=32, prefill_pad=8, prefill_chunk=4,
+              policy="fused_packed")
+    kw.update(cfg_kw)
+    return cfg, Engine(model, params, EngineConfig(**kw))
+
+
+def _drive(eng, prompts, max_new=4):
+    """Submit, then step until drained; returns (outputs by submit order,
+    number of step() calls)."""
+    uids = [eng.submit(p, max_new=max_new) for p in prompts]
+    calls = 0
+    while eng.pending():
+        eng.step()
+        calls += 1
+    fin = {r.uid: r.out for r in eng.finished}
+    return [fin[u] for u in uids], calls
+
+
+def test_tick_log_keeps_one_record_per_step(lm_zoo):
+    cfg, eng = _packed_engine(lm_zoo)
+    assert eng.last_tick is None
+    eng.step()                                  # nothing to do: early return
+    empty = eng.last_tick
+    assert not empty["decoded"] and empty["live"] == 0
+    assert empty["syncs"] == 0 and empty["chunks"] == 0
+    assert set(empty["phases"]) == {"step", "admit"}
+    _, calls = _drive(eng, _prompts(cfg, n=3, lens=(3, 9), seed=5))
+    recs = list(eng._ticks)
+    assert len(recs) == calls + 1
+    assert [r["tick"] for r in recs] == sorted(r["tick"] for r in recs)
+    assert sum(r["chunks"] for r in recs) == eng._prefill_chunks
+    assert sum(r["live"] for r in recs) + 3 == eng._tokens_emitted
+    for r in recs:
+        assert r["decoded"] == ("decode" in r["phases"])
+        assert r["phases"]["step"] >= sum(
+            ns for k, ns in r["phases"].items() if k != "step")
+
+
+@pytest.mark.parametrize("spike_every,telemetry_syncs", [(1, 1), (0, 0)])
+def test_host_syncs_per_tick_follow_the_live_slots(lm_zoo, spike_every,
+                                                   telemetry_syncs):
+    """A tick that finishes no prefill syncs once for the decode's logits,
+    once per sampled token and once per packed pool spike telemetry
+    fetches (the tiny model holds one)."""
+    cfg, eng = _packed_engine(lm_zoo, spike_stats_every=spike_every)
+    _drive(eng, _prompts(cfg, n=3, lens=(3, 9), seed=6), max_new=6)
+    pure = [r for r in eng._ticks if r["decoded"] and r["chunks"] == 0]
+    assert pure and any(r["live"] == 2 for r in pure)
+    for r in pure:
+        assert r["syncs"] == r["live"] + 1 + telemetry_syncs, r
+    assert ("spike_stats" in eng.last_tick["phases"]) == bool(spike_every)
+
+
+@pytest.fixture(scope="module")
+def traced_run(lm_zoo, tmp_path_factory):
+    """The same requests served twice: once with a profiler trace running,
+    once without. Returns (traced outputs, untraced outputs, the host
+    events of the trace as (name, start, end), records made traced)."""
+    from jax.profiler import ProfileData
+
+    cfg, _ = _packed_engine(lm_zoo)
+    prompts = _prompts(cfg, n=3, lens=(3, 9), seed=7)
+    plain, _ = _drive(_packed_engine(lm_zoo)[1], prompts)
+    eng = _packed_engine(lm_zoo)[1]
+    log_dir = str(tmp_path_factory.mktemp("engine-trace"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        traced, _ = _drive(eng, prompts)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(log_dir)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = [(ev.name.split("#", 1)[0], ev.start_ns,
+               ev.start_ns + ev.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("serve.")]
+    return traced, plain, events, list(eng._ticks)
+
+
+def test_phase_spans_nest_in_the_step_span_of_a_trace(traced_run):
+    _, _, events, recs = traced_run
+    assert PHASES <= {name for name, _, _ in events}
+    steps = [(s, e) for name, s, e in events if name == "serve.step"]
+    assert len(steps) == len(recs)
+    for name, s, e in events:
+        if name != "serve.step":
+            assert any(a <= s and e <= b for a, b in steps), name
+    # one prefill span per chunk the engine ran
+    assert sum(name == "serve.prefill" for name, _, _ in events) == \
+        sum(r["chunks"] for r in recs)
+
+
+def test_greedy_outputs_are_identical_with_a_trace_running(traced_run):
+    traced, plain, _, _ = traced_run
+    assert traced == plain
+    assert all(len(out) == 4 for out in traced)
+
+
+def test_stats_derive_tick_percentiles_and_syncs_from_the_tick_log(lm_zoo):
+    cfg, eng = _packed_engine(lm_zoo)
+    _drive(eng, _prompts(cfg, n=3, lens=(3, 9), seed=8))
+    st = eng.stats()
+    decoded = [r for r in eng._ticks if r["decoded"]]
+    assert st["decode_ticks"] == len(decoded)
+    assert st["decode_tick_max_s"] >= st["decode_tick_p99_s"] \
+        >= st["decode_tick_p50_s"] > 0.0
+    assert st["decode_tick_max_s"] == \
+        max(r["phases"]["decode"] for r in decoded) / 1e9
+    assert st["host_syncs_per_tick_mean"] == pytest.approx(
+        np.mean([r["syncs"] for r in decoded]))
+    assert PHASES <= {"serve." + k for k in st["phase_ms"]}
+    for ms in st["phase_ms"].values():
+        assert ms["p99"] >= ms["p50"] >= 0.0
+    for r in eng.finished:
+        assert r.enqueued_t <= r.admitted_t <= r.first_token_t
