@@ -43,7 +43,9 @@ depth elasticity in software:
     slots keep decoding: downstream backpressure without head-of-line
     blocking.
 
-Sampling: greedy or temperature (per request).
+Sampling: greedy or temperature (per request). The greedy slots of a pool
+decode share one jitted row-wise argmax and one fetch of its [max_slots]
+tokens per tick; temperature slots sample one slot at a time.
 
 Observability: every ``step()`` appends one record to a bounded tick log
 (``last_tick``; aggregated in ``stats()``) with the nanoseconds of each
@@ -78,6 +80,10 @@ SPAN_PREFIX = "serve."
 # constructing many engines — compiles each (shape, config) combination
 # exactly once instead of once per engine.
 _JIT_CACHE: dict = {}
+
+# Greedy tokens of a whole pool decode: one row-wise argmax, so a tick
+# fetches one [max_slots] vector however many slots are live.
+_greedy_rows = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
 
 
 def _jitted_steps(model):
@@ -272,6 +278,7 @@ class Engine:
         # window, so stats() percentiles stay O(window) and memory bounded
         self._ticks: deque = deque(maxlen=4096)
         self._rec: dict = {}                # the record of the running step
+        self._greedy = None                 # (pool logits, their greedy tokens)
         self._syncs = 0                     # device->host syncs ever made
         # self-healing state + counters
         self._tokens_emitted = 0
@@ -710,11 +717,25 @@ class Engine:
         self._requeues += 1
 
     def _sample(self, logits: Array, req: Request) -> int:
+        """Every served token comes from here. ``logits`` is one row
+        ``[vocab]`` (a prefill's last position) or a pool decode's
+        ``[max_slots, vocab]``, whose row ``req.slot`` is the request's. A
+        greedy request reads a pool's row from one batched argmax, fetched
+        once per logits array (logits replaced by fault injection are
+        reduced afresh); a temperature request draws from the engine's RNG
+        stream one slot at a time."""
+        pooled = logits.ndim > 1
         if req.temperature <= 0.0:
-            return int(self._sync(jnp.argmax(logits)))
+            if not pooled:
+                return int(self._sync(jnp.argmax(logits)))
+            if self._greedy is None or self._greedy[0] is not logits:
+                self._greedy = (logits, self._sync(_greedy_rows(logits)))
+            self._rec["batched"] += 1
+            return int(self._greedy[1][req.slot])
+        row = logits[req.slot] if pooled else logits
         self._rng, k = jax.random.split(self._rng)
         return int(self._sync(
-            jax.random.categorical(k, logits / req.temperature)))
+            jax.random.categorical(k, row / req.temperature)))
 
     # ------------------------------------------------------ tick telemetry
     def _sync(self, x: Array, fetch: bool = True) -> Any:
@@ -773,9 +794,13 @@ class Engine:
         Every call appends one record to the tick log (``last_tick``), also
         a call that returns early or raises: ``tick`` (the engine tick it
         ran at), ``live`` (slots that sampled a token from the decode),
-        ``decoded``, ``chunks`` (prefill chunks run), ``syncs`` (device->host
-        syncs: the decode's wait, one per sampled token, one per packed
-        pool that spike telemetry fetches, one per guard verdict) and
+        ``batched`` (those of them whose token came from the decode's one
+        batched greedy fetch; ``live - batched`` sampled one slot at a
+        time), ``decoded``, ``chunks`` (prefill chunks run), ``syncs``
+        (device->host syncs: the decode's wait, the batched greedy fetch,
+        one per token sampled alone (a temperature slot, a finished
+        prefill's first token), one per packed pool that spike telemetry
+        fetches, one per guard verdict) and
         ``phases`` (host nanoseconds of each phase that ran). Each phase
         runs in a profiler span named ``serve.<phase>``, nested in
         ``serve.step`` (a step annotation whose ``step_num`` is ``tick``):
@@ -789,10 +814,11 @@ class Engine:
           and the wait for its logits;
         * ``guard``: fault injection and the integrity scan;
         * ``spike_stats``: packed spike telemetry;
-        * ``sample``: stall restore, quarantine, then per slot the sample,
-          emit, finish and slot release."""
-        rec = self._rec = {"tick": self._tick, "live": 0, "decoded": False,
-                           "chunks": 0, "syncs": 0, "phases": {}}
+        * ``sample``: stall restore, quarantine, the batched greedy fetch,
+          then per slot the sample, emit, finish and slot release."""
+        rec = self._rec = {"tick": self._tick, "live": 0, "batched": 0,
+                           "decoded": False, "chunks": 0, "syncs": 0,
+                           "phases": {}}
         syncs0, chunks0 = self._syncs, self._prefill_chunks
         try:
             with self._phase("step", jax.profiler.StepTraceAnnotation,
@@ -878,7 +904,7 @@ class Engine:
             for slot, req in list(self.active.items()):
                 if slot in stalled:
                     continue
-                tok = self._sample(logits[slot], req)
+                tok = self._sample(logits, req)
                 rec["live"] += 1
                 self._emit(req, tok)
                 self.slot_len[slot] += 1
@@ -887,6 +913,7 @@ class Engine:
                         or self.slot_len[slot] >= self.cfg.max_len - 1:
                     self._finish(req, STATUS_DONE)
                     done_slots.append(slot)
+            self._greedy = None
             for slot in done_slots:
                 del self.active[slot]
                 self.slot_len[slot] = 0
@@ -1043,13 +1070,18 @@ class Engine:
         decode_s = [r["phases"]["decode"] / 1e9 for r in self._ticks
                     if "decode" in r["phases"]]
         if decode_s:
+            decoded = [r for r in self._ticks if r["decoded"]]
+            live = sum(r["live"] for r in decoded)
             out.update({
                 "decode_ticks": len(decode_s),
                 "decode_tick_p50_s": float(np.percentile(decode_s, 50)),
                 "decode_tick_p99_s": float(np.percentile(decode_s, 99)),
                 "decode_tick_max_s": float(max(decode_s)),
                 "host_syncs_per_tick_mean": float(np.mean(
-                    [r["syncs"] for r in self._ticks if r["decoded"]]))})
+                    [r["syncs"] for r in decoded])),
+                # share of decoded tokens taken from the batched greedy fetch
+                "batched_sample_share": (sum(r["batched"] for r in decoded)
+                                         / live if live else 0.0)})
         by_phase: dict = {}
         for r in self._ticks:
             for name, ns in r["phases"].items():

@@ -302,14 +302,22 @@ def test_tick_log_keeps_one_record_per_step(lm_zoo):
 def test_host_syncs_per_tick_follow_the_live_slots(lm_zoo, spike_every,
                                                    telemetry_syncs):
     """A tick that finishes no prefill syncs once for the decode's logits,
-    once per sampled token and once per packed pool spike telemetry
-    fetches (the tiny model holds one)."""
-    cfg, eng = _packed_engine(lm_zoo, spike_stats_every=spike_every)
-    _drive(eng, _prompts(cfg, n=3, lens=(3, 9), seed=6), max_new=6)
+    once for the batched greedy tokens of all its live slots and once per
+    packed pool spike telemetry fetches (the tiny model holds one): the
+    count no longer follows the live slots."""
+    cfg, eng = _packed_engine(lm_zoo, spike_stats_every=spike_every,
+                              max_slots=4)
+    # staggered lengths: pure decode ticks at 4, 3, 2 and 1 live slots
+    for i, p in enumerate(_prompts(cfg, n=4, lens=(3, 9), seed=6)):
+        eng.submit(p, max_new=4 + 3 * i)
+    while eng.pending():
+        eng.step()
     pure = [r for r in eng._ticks if r["decoded"] and r["chunks"] == 0]
-    assert pure and any(r["live"] == 2 for r in pure)
+    assert {1, 2} <= {r["live"] for r in pure}
+    assert max(r["live"] for r in pure) >= 3
     for r in pure:
-        assert r["syncs"] == r["live"] + 1 + telemetry_syncs, r
+        assert r["syncs"] == 2 + telemetry_syncs, r
+        assert r["batched"] == r["live"], r
     assert ("spike_stats" in eng.last_tick["phases"]) == bool(spike_every)
 
 
