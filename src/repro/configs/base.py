@@ -51,6 +51,16 @@ class ModelConfig:
     ssm_ngroups: int = 1
     # --- hybrid (zamba2) ---
     attn_every: int = 0              # shared attention applied every k layers
+    # --- hybrid by layer pattern (granitemoehybrid's ``layer_types``) ---
+    # one "mamba" | "attention" entry per layer, each layer with its own
+    # weights and, when d_ff > 0, its own SwiGLU MLP; () = no pattern
+    layer_types: tuple = ()
+    # --- published multipliers (granitemoehybrid); defaults change nothing
+    position_embedding_type: str = "rope"   # rope | nope (no position code)
+    attention_multiplier: float = 0.0       # softmax scale; 0 = head_dim**-0.5
+    embedding_multiplier: float = 1.0       # embeddings scaled on lookup
+    residual_multiplier: float = 1.0        # each branch scaled before its add
+    logits_scaling: float = 1.0             # read-out logits divided by this
     # --- enc-dec (seamless-m4t) ---
     n_enc_layers: int = 0
     d_src: int = 0                   # precomputed frontend embedding dim
@@ -115,6 +125,12 @@ class ModelConfig:
             # normalize preset names so configs hash/compare consistently
             # (they key jit caches in the serving engine)
             object.__setattr__(self, "policy", resolved)
+        if self.layer_types and (
+                len(self.layer_types) != self.n_layers
+                or set(self.layer_types) - {"mamba", "attention"}):
+            raise ValueError(
+                f"layer_types needs one 'mamba' or 'attention' per layer "
+                f"({self.n_layers}), got {self.layer_types}")
 
     @property
     def exec_policy(self) -> ExecutionPolicy:
@@ -138,6 +154,15 @@ class ModelConfig:
         ``ssm_chunk`` boundaries for the chunked scan to decompose exactly
         into the blocking one."""
         return self.ssm_chunk if self.family in ("ssm", "hybrid") else 1
+
+    @property
+    def layer_period(self) -> int:
+        """Layers in one period of ``layer_types``: the shortest prefix that
+        repeats to the whole pattern (the pattern model scans whole
+        periods, so its HLO stays one period in size)."""
+        t, n = self.layer_types, len(self.layer_types)
+        return next(p for p in range(1, n + 1)
+                    if n % p == 0 and t == t[:p] * (n // p))
 
     @property
     def d_inner(self) -> int:        # ssm inner width

@@ -11,9 +11,9 @@ import dataclasses
 from typing import Callable
 
 from .base import ModelConfig, SHAPES, ShapeSpec, cells_for
-from . import (llama4_scout_17b_a16e, mamba2_130m, olmoe_1b_7b,
-               phi3_vision_4_2b, qwen1_5_32b, qwen2_5_3b, qwen3_1_7b,
-               seamless_m4t_large_v2, yi_9b, zamba2_7b)
+from . import (granite_4_0_h_micro, llama4_scout_17b_a16e, mamba2_130m,
+               olmoe_1b_7b, phi3_vision_4_2b, qwen1_5_32b, qwen2_5_3b,
+               qwen3_1_7b, seamless_m4t_large_v2, yi_9b, zamba2_7b)
 
 ARCHS: dict[str, ModelConfig] = {
     c.name: c for c in [
@@ -26,6 +26,7 @@ ARCHS: dict[str, ModelConfig] = {
         llama4_scout_17b_a16e.CONFIG,
         olmoe_1b_7b.CONFIG,
         zamba2_7b.CONFIG,
+        granite_4_0_h_micro.CONFIG,
         seamless_m4t_large_v2.CONFIG,
     ]
 }
@@ -69,7 +70,11 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
                      n_shared_experts=cfg.n_shared_experts)
     if cfg.family in ("ssm", "hybrid"):
         small.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16, ssm_conv=4)
-    if cfg.family == "hybrid":
+    if cfg.layer_types:
+        # two periods, each with both layer types
+        small.update(n_layers=6,
+                     layer_types=("mamba", "attention", "mamba") * 2)
+    elif cfg.family == "hybrid":
         small.update(attn_every=2)
     if cfg.family == "vlm":
         small.update(n_img_tokens=16, d_vision=32, vision_pool_window=2)

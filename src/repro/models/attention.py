@@ -56,6 +56,19 @@ def attn_init(rng: Array, cfg: ModelConfig, d_model: Optional[int] = None,
     return p
 
 
+def attn_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of the scores: the config's published
+    ``attention_multiplier`` where it gives one, else 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or cfg.resolved_head_dim ** -0.5
+
+
+def _position_code(cfg: ModelConfig, x: Array, positions: Array) -> Array:
+    """Rotary position code on q or k; none under ``nope``."""
+    if cfg.position_embedding_type == "nope":
+        return x
+    return apply_rope(x, positions, cfg.rope_theta)
+
+
 def _project_qkv(p: dict, cfg: ModelConfig, x: Array, positions: Array,
                  h: int, hkv: int):
     b, s, _ = x.shape
@@ -66,9 +79,8 @@ def _project_qkv(p: dict, cfg: ModelConfig, x: Array, positions: Array,
     if cfg.qk_norm:
         q = rmsnorm_apply(p["q_norm"], q, cfg.rms_eps)
         k = rmsnorm_apply(p["k_norm"], k, cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    return _position_code(cfg, q, positions), \
+        _position_code(cfg, k, positions), v
 
 
 def _expand_kv(k: Array, h: int) -> Array:
@@ -159,7 +171,7 @@ def attn_apply(p: dict, cfg: ModelConfig, x: Array, positions: Array,
     hkv = n_kv or (cfg.n_kv_heads or h)
     dh = cfg.resolved_head_dim
     b, s, _ = x.shape
-    scale = dh ** -0.5
+    scale = attn_scale(cfg)
 
     if cfg.attention_kind == "qk_spiking":
         return _qk_spiking_apply(p, cfg, x, h, hkv)
@@ -170,7 +182,7 @@ def attn_apply(p: dict, cfg: ModelConfig, x: Array, positions: Array,
         q = dense_apply(p["wq"], x).reshape(b, s, h, dh)
         if cfg.qk_norm:
             q = rmsnorm_apply(p["q_norm"], q, cfg.rms_eps)
-        q = apply_rope(q, positions, cfg.rope_theta)
+        q = _position_code(cfg, q, positions)
         k, v = kv_override
     k = _expand_kv(k, h)
     v = _expand_kv(v, h)
@@ -205,7 +217,7 @@ def attn_prefill(p: dict, cfg: ModelConfig, x: Array, positions: Array,
         return out, (empty, empty)
     q, k, v = _project_qkv(p, cfg, x, positions, h, hkv)
     ke, ve = _expand_kv(k, h), _expand_kv(v, h)
-    scale = dh ** -0.5
+    scale = attn_scale(cfg)
     if s * s > cfg.flash_threshold ** 2:
         out = _attn_chunked(q, ke, ve, scale, True, cfg.attn_q_block,
                             cfg.attn_kv_block)
@@ -243,7 +255,7 @@ def attn_append(p: dict, cfg: ModelConfig, x: Array,
     hkv = n_kv or (cfg.n_kv_heads or h)
     dh = cfg.resolved_head_dim
     b, c, _ = x.shape
-    scale = dh ** -0.5
+    scale = attn_scale(cfg)
 
     if cfg.attention_kind == "qk_spiking":
         # token-local: the chunk is self-contained; packed mode refreshes
@@ -288,11 +300,18 @@ def attn_append(p: dict, cfg: ModelConfig, x: Array,
 def attn_decode(p: dict, cfg: ModelConfig, x: Array, pos: Array,
                 cache_k: Array, cache_v: Array, cache_len: Array,
                 *, n_heads: Optional[int] = None,
-                n_kv: Optional[int] = None) -> tuple[Array, tuple[Array, Array]]:
+                n_kv: Optional[int] = None,
+                rows: bool = False) -> tuple[Array, tuple[Array, Array]]:
     """One-token decode. x: [B, 1, D]; cache_[kv]: [B, S_max, Hkv, Dh];
     cache_len: [] scalar OR [B] vector of per-sequence valid lengths (the
     serving engine's slot pool uses the vector form; the new token is
     written at index cache_len per sequence).
+
+    ``rows``: read the cache as it is and return the new token's K/V rows
+    ([B, 1, Hkv, Dh]) for the caller to write, instead of the updated
+    cache; the new token joins the softmax as its own term (the frozen
+    form below). For a caller that holds the cache in a larger buffer and
+    writes the rows there in place.
 
     When the cache is sequence-sharded over 'data' (long_500k), the masked
     softmax below reduces over a sharded axis — GSPMD lowers it to the
@@ -302,7 +321,7 @@ def attn_decode(p: dict, cfg: ModelConfig, x: Array, pos: Array,
     hkv = n_kv or (cfg.n_kv_heads or h)
     dh = cfg.resolved_head_dim
     b = x.shape[0]
-    scale = dh ** -0.5
+    scale = attn_scale(cfg)
 
     if cfg.attention_kind == "qk_spiking":
         if cfg.exec_policy.packed:
@@ -318,7 +337,7 @@ def attn_decode(p: dict, cfg: ModelConfig, x: Array, pos: Array,
         positions = jnp.broadcast_to(pos[None, None], (b, 1))
     q, k_new, v_new = _project_qkv(p, cfg, x, positions, h, hkv)
 
-    if cfg.decode_cp_axis:
+    if cfg.decode_cp_axis or rows:
         # context-parallel decode (cache SEQUENCE-sharded over an axis):
         # the cache is FROZEN — a dynamic-index write into a seq-sharded
         # buffer makes GSPMD gather the whole cache (measured: 56 GB/step
@@ -351,7 +370,7 @@ def attn_decode(p: dict, cfg: ModelConfig, x: Array, pos: Array,
         out = out / denom[..., None].astype(q.dtype)     # [b,hkv,g,1,dh]
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, 1, h * dh)
         out = dense_apply(p["wo"], out)
-        return out, (cache_k, cache_v)
+        return out, ((k_new, v_new) if rows else (cache_k, cache_v))
 
     # write the new K/V row at index cache_len (per sequence)
     if jnp.ndim(cache_len) == 0:

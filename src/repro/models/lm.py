@@ -7,6 +7,9 @@ One parameterized decoder stack covers five families:
   ssm     (mamba2-130m)                                — Mamba2/SSD blocks
   hybrid  (zamba2-7b)                                  — Mamba2 + ONE shared
           attention block applied every ``attn_every`` layers (grouped scan)
+          (granite-4.0-h-micro)                        — a ``layer_types``
+          pattern of unshared Mamba2 and attention layers, each with its
+          own MLP (one scan over whole periods)
   vlm     (phi-3-vision-4.2b)                          — dense decoder with a
           precomputed-patch-embedding prefix (+ optional W2TTFS patch merge)
 
@@ -31,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Any, Optional
 
 import jax
@@ -57,8 +61,24 @@ def _block_kind(cfg: ModelConfig) -> str:
             "ssm": "mamba", "hybrid": "mamba"}[cfg.family]
 
 
-def block_init(rng: Array, cfg: ModelConfig) -> dict:
-    kind = _block_kind(cfg)
+# a ``layer_types`` entry -> (its cache and params key, its block kind)
+_PATTERN = {"mamba": ("mamba", "mamba"), "attention": ("attn", "attn_mlp")}
+
+
+def _pattern_runs(cfg: ModelConfig) -> list:
+    """(key, block kind, index among the key's runs, length) of each run
+    of like layers in one period of ``cfg.layer_types``."""
+    runs, seen = [], {}
+    for t, grp in itertools.groupby(cfg.layer_types[:cfg.layer_period]):
+        key, kind = _PATTERN[t]
+        runs.append((key, kind, seen.get(key, 0), len(list(grp))))
+        seen[key] = seen.get(key, 0) + 1
+    return runs
+
+
+def block_init(rng: Array, cfg: ModelConfig, kind: Optional[str] = None
+               ) -> dict:
+    kind = kind or _block_kind(cfg)
     r1, r2 = jax.random.split(rng)
     if kind == "attn_mlp":
         return {"ln1": rmsnorm_init(cfg.d_model, cfg.param_dtype),
@@ -71,8 +91,12 @@ def block_init(rng: Array, cfg: ModelConfig) -> dict:
                 "ln2": rmsnorm_init(cfg.d_model, cfg.param_dtype),
                 "moe": moe_init(r2, cfg)}
     if kind == "mamba":
-        return {"ln1": rmsnorm_init(cfg.d_model, cfg.param_dtype),
-                "mamba": mamba_init(r1, cfg)}
+        p = {"ln1": rmsnorm_init(cfg.d_model, cfg.param_dtype),
+             "mamba": mamba_init(r1, cfg)}
+        if cfg.layer_types and cfg.d_ff:     # a pattern layer's own MLP
+            p["ln2"] = rmsnorm_init(cfg.d_model, cfg.param_dtype)
+            p["mlp"] = mlp_init(r2, cfg)
+        return p
     raise ValueError(kind)
 
 
@@ -91,8 +115,28 @@ def _zero_aux() -> dict:
             "dropped_frac": jnp.zeros((), jnp.float32)}
 
 
+def _res(cfg: ModelConfig, x: Array, h: Array) -> Array:
+    """x plus one branch, scaled by the config's residual multiplier."""
+    m = cfg.residual_multiplier
+    return x + h if m == 1.0 else x + h * m
+
+
+def _ffn_tail(p: dict, cfg: ModelConfig, x: Array) -> tuple[Array, dict]:
+    """The block's second half: its MLP or MoE on the normed stream (a
+    Mamba block without an MLP passes x through)."""
+    if "mlp" in p:
+        y = rmsnorm_apply(p["ln2"], x, cfg.rms_eps)
+        return _res(cfg, x, mlp_apply(p["mlp"], cfg, y)), _zero_aux()
+    if "moe" in p:
+        moe_y, aux = moe_apply(p["moe"], cfg,
+                               rmsnorm_apply(p["ln2"], x, cfg.rms_eps))
+        return x + moe_y, aux
+    return x, _zero_aux()
+
+
 def block_apply(p: dict, cfg: ModelConfig, x: Array, positions: Array,
-                *, causal: bool = True) -> tuple[Array, dict]:
+                *, causal: bool = True, kind: Optional[str] = None
+                ) -> tuple[Array, dict]:
     """Full-sequence block forward (train). Returns (x, moe_aux).
 
     With ``cfg.seq_shard`` (Megatron-SP) the residual stream lives
@@ -101,90 +145,111 @@ def block_apply(p: dict, cfg: ModelConfig, x: Array, positions: Array,
     all-gather and exited through a reduce-scatter — both explicit, both on
     bf16 activations (left to itself GSPMD gathers f32 weights instead).
     """
-    kind = _block_kind(cfg)
-    aux = _zero_aux()
+    kind = kind or _block_kind(cfg)
     sp = cfg.seq_shard
     x = shard_act(x, "dp", "model" if sp else None, None)
+    xn = rmsnorm_apply(p["ln1"], x, cfg.rms_eps)
     if kind in ("attn_mlp", "attn_moe"):
-        h = attn_apply(p["attn"], cfg, rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                       positions, causal=causal)
-        x = x + h
-        y = rmsnorm_apply(p["ln2"], x, cfg.rms_eps)
-        if kind == "attn_mlp":
-            x = x + mlp_apply(p["mlp"], cfg, y)
-        else:
-            moe_y, aux = moe_apply(p["moe"], cfg, y)
-            x = x + moe_y
+        h = attn_apply(p["attn"], cfg, xn, positions, causal=causal)
     else:  # mamba
-        x = x + mamba_apply(p["mamba"], cfg,
-                            rmsnorm_apply(p["ln1"], x, cfg.rms_eps))
+        h = mamba_apply(p["mamba"], cfg, xn)
+    x, aux = _ffn_tail(p, cfg, _res(cfg, x, h))
     return shard_act(x, "dp", "model" if sp else None, None), aux
 
 
-def block_prefill(p: dict, cfg: ModelConfig, x: Array, positions: Array
-                  ) -> tuple[Array, Any]:
-    """Block forward that also emits its cache entry."""
-    kind = _block_kind(cfg)
+def block_prefill(p: dict, cfg: ModelConfig, x: Array, positions: Array,
+                  *, kind: Optional[str] = None,
+                  n_valid: Optional[Array] = None) -> tuple[Array, Any]:
+    """Block forward that also emits its cache entry. ``n_valid``: the
+    prompts' true lengths (right pads after them leave SSM state alone)."""
+    kind = kind or _block_kind(cfg)
     x = shard_act(x, "dp", None, None)
+    xn = rmsnorm_apply(p["ln1"], x, cfg.rms_eps)
     if kind in ("attn_mlp", "attn_moe"):
-        h, kv = attn_prefill(p["attn"], cfg,
-                             rmsnorm_apply(p["ln1"], x, cfg.rms_eps), positions)
-        x = x + h
-        y = rmsnorm_apply(p["ln2"], x, cfg.rms_eps)
-        if kind == "attn_mlp":
-            x = x + mlp_apply(p["mlp"], cfg, y)
-        else:
-            moe_y, _ = moe_apply(p["moe"], cfg, y)
-            x = x + moe_y
-        return x, kv
-    out, st = mamba_apply(p["mamba"], cfg,
-                          rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                          return_state=True)
-    return x + out, st
+        h, st = attn_prefill(p["attn"], cfg, xn, positions)
+    else:
+        h, st = mamba_apply(p["mamba"], cfg, xn, return_state=True,
+                            n_valid=n_valid)
+    return _ffn_tail(p, cfg, _res(cfg, x, h))[0], st
 
 
 def block_decode(p: dict, cfg: ModelConfig, x: Array, cache_l: Any,
                  cache_len: Array) -> tuple[Array, Any]:
     kind = _block_kind(cfg)
+    xn = rmsnorm_apply(p["ln1"], x, cfg.rms_eps)
     if kind in ("attn_mlp", "attn_moe"):
-        h, (k, v) = attn_decode(p["attn"], cfg,
-                                rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                                cache_len, cache_l[0], cache_l[1], cache_len)
-        x = x + h
-        y = rmsnorm_apply(p["ln2"], x, cfg.rms_eps)
-        if kind == "attn_mlp":
-            x = x + mlp_apply(p["mlp"], cfg, y)
-        else:
-            moe_y, _ = moe_apply(p["moe"], cfg, y)
-            x = x + moe_y
-        return x, (k, v)
-    out, st = mamba_decode_step(p["mamba"], cfg,
-                                rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                                cache_l)
-    return x + out, st
+        h, st = attn_decode(p["attn"], cfg, xn, cache_len, cache_l[0],
+                            cache_l[1], cache_len)
+    else:
+        h, st = mamba_decode_step(p["mamba"], cfg, xn, cache_l)
+    return _ffn_tail(p, cfg, _res(cfg, x, h))[0], st
 
 
 def block_append(p: dict, cfg: ModelConfig, x: Array, cache_l: Any,
-                 cache_len: Array) -> tuple[Array, Any]:
+                 cache_len: Array, *, kind: Optional[str] = None,
+                 n_valid: Optional[Array] = None) -> tuple[Array, Any]:
     """Chunked-prefill block forward: C tokens appended to an existing
-    cache entry (the multi-token generalization of ``block_decode``)."""
-    kind = _block_kind(cfg)
+    cache entry (the multi-token generalization of ``block_decode``);
+    ``n_valid``: the chunk's valid tokens, right pads after them."""
+    kind = kind or _block_kind(cfg)
+    xn = rmsnorm_apply(p["ln1"], x, cfg.rms_eps)
     if kind in ("attn_mlp", "attn_moe"):
-        h, (k, v) = attn_append(p["attn"], cfg,
-                                rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                                cache_l[0], cache_l[1], cache_len)
-        x = x + h
-        y = rmsnorm_apply(p["ln2"], x, cfg.rms_eps)
-        if kind == "attn_mlp":
-            x = x + mlp_apply(p["mlp"], cfg, y)
-        else:
-            moe_y, _ = moe_apply(p["moe"], cfg, y)
-            x = x + moe_y
-        return x, (k, v)
-    out, st = mamba_apply(p["mamba"], cfg,
-                          rmsnorm_apply(p["ln1"], x, cfg.rms_eps),
-                          init_state=cache_l, return_state=True)
-    return x + out, st
+        h, st = attn_append(p["attn"], cfg, xn, cache_l[0], cache_l[1],
+                            cache_len)
+    else:
+        h, st = mamba_apply(p["mamba"], cfg, xn, init_state=cache_l,
+                            return_state=True, n_valid=n_valid)
+    return _ffn_tail(p, cfg, _res(cfg, x, h))[0], st
+
+
+def _at(st: Any, g: Array, i: Array) -> Any:
+    """Layer [g, i] of a run's state stack [periods, run length, ...]."""
+    return jax.tree_util.tree_map(lambda a: a[g, i], st)
+
+
+def _put(st: Any, g: Array, i: Array, new: Any) -> Any:
+    """A run's state stack with layer [g, i] replaced by ``new``."""
+    return jax.tree_util.tree_map(
+        lambda a, n: jax.lax.dynamic_update_slice(
+            a, n.astype(a.dtype)[None, None], (g, i) + (0,) * n.ndim),
+        st, new)
+
+
+def _pattern_decode(cfg: ModelConfig, cache_len: Array, kind: str, p: dict,
+                    x: Array, st: Any, g: Array, i: Array):
+    """One layer of the pattern's pool decode: a Mamba layer rewrites its
+    SSM and conv state in its run's stack; an attention layer reads its KV
+    rows where they lie and emits the new token's K/V rows, which
+    ``_put_kv_rows`` writes once the scan is over (the KV stacks then keep
+    the layout they have outside the scan)."""
+    xn = rmsnorm_apply(p["ln1"], x, cfg.rms_eps)
+    rows = None
+    if kind == "mamba":
+        h, new = mamba_decode_step(p["mamba"], cfg, xn, _at(st, g, i))
+        st = _put(st, g, i, new)
+    else:
+        h, rows = attn_decode(p["attn"], cfg, xn, cache_len, st[0][g, i],
+                              st[1][g, i], cache_len, rows=True)
+    return _ffn_tail(p, cfg, _res(cfg, x, h))[0], st, rows
+
+
+def _put_kv_rows(layers: dict, rows: dict, cache_len: Array) -> dict:
+    """Write each attention layer's new K/V row of every slot at that
+    slot's length, in place: one small update per (layer, slot)."""
+    def put(stack, new):             # [P, N, B, S, H, D], [P, N, B, 1, H, D]
+        n_per, n, b = new.shape[:3]
+        lens = jnp.broadcast_to(jnp.asarray(cache_len), (b,))
+
+        def one(t, a):
+            g, i, s = t // (n * b), t // b % n, t % b
+            return jax.lax.dynamic_update_slice(
+                a, new[g, i, s][None, None, None].astype(a.dtype),
+                (g, i, s, lens[s], 0, 0))
+        return jax.lax.fori_loop(0, n_per * n * b, one, stack)
+
+    return dict(layers, attn=tuple(
+        tuple(put(a, r) for a, r in zip(kv, new))
+        for kv, new in zip(layers["attn"], rows["attn"])))
 
 
 def _pad_kv_layers(layers: Any, max_len: int) -> Any:
@@ -222,14 +287,13 @@ class LM:
         params: dict = {
             "embed": embedding_init(r_emb, cfg.vocab_size, cfg.d_model,
                                     cfg.param_dtype),
-            "blocks": jax.vmap(lambda r: block_init(r, cfg))(
-                jax.random.split(r_blocks, cfg.n_layers)),
+            "blocks": self._init_blocks(r_blocks),
             "final_norm": rmsnorm_init(cfg.d_model, cfg.param_dtype),
         }
         if not cfg.tie_embeddings:
             params["head"] = dense_init(r_head, cfg.d_model, cfg.vocab_size,
                                         dtype=cfg.param_dtype)
-        if cfg.family == "hybrid":
+        if cfg.family == "hybrid" and not cfg.layer_types:
             params["shared_attn"] = shared_attn_init(r_shared, cfg)
         if cfg.family == "vlm":
             params["vision_proj"] = dense_init(r_vis, cfg.d_vision,
@@ -237,11 +301,81 @@ class LM:
                                                dtype=cfg.param_dtype)
         return params
 
+    def _init_blocks(self, rng: Array) -> dict:
+        """Stacked block params: one stack over all layers, or under a
+        ``layer_types`` pattern the layout ``_pattern`` scans."""
+        cfg = self.cfg
+        if not cfg.layer_types:
+            return jax.vmap(lambda r: block_init(r, cfg))(
+                jax.random.split(rng, cfg.n_layers))
+        n_per = cfg.n_layers // cfg.layer_period
+        out: dict = {}
+        for key, kind, _, n in _pattern_runs(cfg):
+            rng, r = jax.random.split(rng)
+            stack = jax.vmap(lambda r, kind=kind: block_init(r, cfg, kind))(
+                jax.random.split(r, n_per * n))
+            out.setdefault(key, []).append(jax.tree_util.tree_map(
+                lambda a: a.reshape(n_per, n, *a.shape[1:]), stack))
+        return {k: tuple(v) for k, v in out.items()}
+
+    def _pattern(self, blocks: dict, x: Array, layer, states=None
+                 ) -> tuple[Array, dict]:
+        """The ``layer_types`` stack as ONE scan over whole periods, and
+        inside a period one scan per run of like layers, so the HLO holds
+        one period. ``blocks``, ``states`` (the per-layer cache, or None)
+        and the outputs share one layout, ``{"mamba": runs, "attn":
+        runs}``: per type, one entry per run of that type in the period,
+        stacked [periods, run length, ...].
+
+        ``layer(kind, p_l, x, run_st, g, i) -> (x, run_st, out)`` runs
+        layer ``i`` of period ``g`` of a run: ``run_st`` is the run's whole
+        state stack, which the layer may update where it lies (in place,
+        when the caller donates the cache); ``out`` is what the layer
+        emits (a prefill's new state, a decode's new KV rows), stacked like
+        ``blocks``. Weights and state are read at [g, i] of their stacks
+        inside the run's scan, one slice per layer. Returns (x, states,
+        outputs)."""
+        runs = _pattern_runs(self.cfg)
+
+        def period_body(carry, _):
+            x, st, g = carry
+            st = None if st is None else {k: list(v) for k, v in st.items()}
+            outs = {key: [] for key, _, _, _ in runs}
+            for key, kind, j, n in runs:
+                def layer_body(c, i, key=key, kind=kind, j=j):
+                    x, run_st = c
+                    p_l = jax.tree_util.tree_map(lambda a: a[g, i],
+                                                 blocks[key][j])
+                    x, run_st, out = layer(kind, p_l, x, run_st, g, i)
+                    return (x, run_st), out
+
+                run_st = None if st is None else st[key][j]
+                (x, run_st), out = jax.lax.scan(layer_body, (x, run_st),
+                                                jnp.arange(n))
+                if st is not None:
+                    st[key][j] = run_st
+                outs[key].append(out)
+            st = None if st is None else {k: tuple(v) for k, v in st.items()}
+            return (x, st, g + 1), {k: tuple(v) for k, v in outs.items()}
+
+        n_per = self.cfg.n_layers // self.cfg.layer_period
+        (x, states, _), outs = jax.lax.scan(
+            period_body, (x, states, jnp.int32(0)), None, length=n_per)
+        return x, states, outs
+
     # ------------------------------------------------------------ embeddings
+    def _embed_tokens(self, params: dict, tokens: Array) -> Array:
+        """Token embeddings in the compute dtype, times the config's
+        embedding multiplier."""
+        cfg = self.cfg
+        x = embedding_lookup(params["embed"], tokens, cfg.dtype)
+        m = cfg.embedding_multiplier
+        return x if m == 1.0 else x * m
+
     def _embed(self, params: dict, batch: dict) -> tuple[Array, Array]:
         """-> (x [B,S,D], positions [B,S])."""
         cfg = self.cfg
-        x = embedding_lookup(params["embed"], batch["tokens"], cfg.dtype)
+        x = self._embed_tokens(params, batch["tokens"])
         if cfg.family == "vlm" and "img_embeds" in batch:
             img = batch["img_embeds"].astype(cfg.dtype)
             if cfg.vision_pool_window > 1:
@@ -279,7 +413,13 @@ class LM:
 
         body = self._maybe_remat(body_plain)
 
-        if cfg.family == "hybrid":
+        if cfg.layer_types:
+            def layer(kind, p_l, x, st, g, i):
+                return self._maybe_remat(lambda p, x: block_apply(
+                    p, cfg, x, positions, kind=kind)[0])(p_l, x), st, None
+            x = self._pattern(params["blocks"], x, layer)[0]
+            aux = _zero_aux()           # no MoE in a pattern
+        elif cfg.family == "hybrid":
             x, aux = self._hybrid_train(params, x, positions, body)
         elif cfg.scan_layers:
             def scan_body(carry, p_l):
@@ -334,8 +474,11 @@ class LM:
     # -------------------------------------------------------------- readout
     def _logits(self, params: dict, x: Array) -> Array:
         if self.cfg.tie_embeddings:
-            return embedding_logits(params["embed"], x)
-        return dense_apply(params["head"], x.astype(jnp.float32))
+            logits = embedding_logits(params["embed"], x)
+        else:
+            logits = dense_apply(params["head"], x.astype(jnp.float32))
+        scale = self.cfg.logits_scaling
+        return logits if scale == 1.0 else logits / scale
 
     # ------------------------------------------------------------------ loss
     def loss(self, params: dict, batch: dict) -> tuple[Array, dict]:
@@ -368,22 +511,32 @@ class LM:
         ``return_all_logits`` gives [B,S,V] (serving engines pick the last
         REAL token's position when prompts are right-padded).
         ``max_len`` > S pads the KV cache with headroom so decode_step can
-        append new tokens directly."""
+        append new tokens directly. ``batch["lengths"]`` ([B], optional)
+        gives right-padded prompts' true lengths: the pads then leave the
+        SSM state alone (attention pads are causal-inert anyway)."""
         cfg = self.cfg
         x, positions = self._embed(params, batch)
+        n_valid = batch.get("lengths")
 
-        if cfg.family == "hybrid":
-            x, cache = self._hybrid_prefill(params, x, positions)
+        if cfg.layer_types:
+            def layer(kind, p_l, x, st, g, i):
+                x, out = block_prefill(p_l, cfg, x, positions, kind=kind,
+                                       n_valid=n_valid)
+                return x, st, out
+            x, _, cache = self._pattern(params["blocks"], x, layer)
+        elif cfg.family == "hybrid":
+            x, cache = self._hybrid_prefill(params, x, positions, n_valid)
         elif cfg.scan_layers:
             def scan_body(carry, p_l):
-                y, c = block_prefill(p_l, cfg, carry, positions)
+                y, c = block_prefill(p_l, cfg, carry, positions,
+                                     n_valid=n_valid)
                 return y, c
             x, cache = jax.lax.scan(scan_body, x, params["blocks"])
         else:
             entries = []
             for i in range(cfg.n_layers):
                 p_l = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
-                x, c = block_prefill(p_l, cfg, x, positions)
+                x, c = block_prefill(p_l, cfg, x, positions, n_valid=n_valid)
                 entries.append(c)
             cache = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *entries)
         x = rmsnorm_apply(params["final_norm"], x, cfg.rms_eps)
@@ -397,7 +550,7 @@ class LM:
                  "len": jnp.array(positions.shape[1], jnp.int32)}
         return logits, cache
 
-    def _hybrid_prefill(self, params, x, positions):
+    def _hybrid_prefill(self, params, x, positions, n_valid=None):
         cfg = self.cfg
         k = cfg.attn_every
         ng = cfg.n_layers // k
@@ -414,7 +567,8 @@ class LM:
             x = x + mlp_apply(shared["mlp"], cfg,
                               rmsnorm_apply(shared["ln2"], x, cfg.rms_eps))
             x, states = jax.lax.scan(
-                lambda c, p: block_prefill(p, cfg, c, positions), x, p_g)
+                lambda c, p: block_prefill(p, cfg, c, positions,
+                                           n_valid=n_valid), x, p_g)
             return x, {"attn": kv, "mamba": states}
 
         x, cache = jax.lax.scan(group_body, x, blocks_g)
@@ -428,10 +582,16 @@ class LM:
         pools in the serving engine)."""
         cfg = self.cfg
         cache_len = cache["len"]
-        x = embedding_lookup(params["embed"], tokens, cfg.dtype)
+        x = self._embed_tokens(params, tokens)
         x = shard_act(x, "dp", None, None)
 
-        if cfg.family == "hybrid":
+        if cfg.layer_types:
+            x, layers, rows = self._pattern(
+                params["blocks"], x,
+                functools.partial(_pattern_decode, cfg, cache_len),
+                cache["layers"])
+            layers = _put_kv_rows(layers, rows, cache_len)
+        elif cfg.family == "hybrid":
             x, layers = self._hybrid_decode(params, x, cache)
         elif cfg.scan_layers:
             def scan_body(carry, inp):
@@ -480,8 +640,8 @@ class LM:
         return x, layers
 
     # -------------------------------------------------------- chunked prefill
-    def prefill_chunk(self, params: dict, tokens: Array, cache: dict
-                      ) -> tuple[Array, dict]:
+    def prefill_chunk(self, params: dict, tokens: Array, cache: dict,
+                      n_valid: Optional[Array] = None) -> tuple[Array, dict]:
         """Continued prefill: C tokens appended to an existing cache.
 
         tokens: [B, C] int32; cache: an ``init_cache``-layout pytree whose
@@ -491,18 +651,28 @@ class LM:
         bit-identical to one blocking ``prefill`` pass — the serving
         engine's elastic-FIFO prefill unit (decode ticks interleave between
         chunks, so one long prompt cannot stall the decode pipeline).
+        ``n_valid`` (scalar or [B], optional): the chunk's valid tokens;
+        the right pads after them leave the SSM state alone.
         """
         cfg = self.cfg
         cache_len = cache["len"]
-        x = embedding_lookup(params["embed"], tokens, cfg.dtype)
+        x = self._embed_tokens(params, tokens)
         x = shard_act(x, "dp", None, None)
 
-        if cfg.family == "hybrid":
-            x, layers = self._hybrid_append(params, x, cache)
+        if cfg.layer_types:
+            def layer(kind, p_l, x, st, g, i):
+                x, new = block_append(p_l, cfg, x, _at(st, g, i), cache_len,
+                                      kind=kind, n_valid=n_valid)
+                return x, _put(st, g, i, new), None
+            x, layers, _ = self._pattern(params["blocks"], x, layer,
+                                         cache["layers"])
+        elif cfg.family == "hybrid":
+            x, layers = self._hybrid_append(params, x, cache, n_valid)
         elif cfg.scan_layers:
             def scan_body(carry, inp):
                 p_l, c_l = inp
-                y, nc = block_append(p_l, cfg, carry, c_l, cache_len)
+                y, nc = block_append(p_l, cfg, carry, c_l, cache_len,
+                                     n_valid=n_valid)
                 return y, nc
             x, layers = jax.lax.scan(scan_body, x,
                                      (params["blocks"], cache["layers"]))
@@ -511,14 +681,15 @@ class LM:
             for i in range(cfg.n_layers):
                 p_l = jax.tree_util.tree_map(lambda a: a[i], params["blocks"])
                 c_l = jax.tree_util.tree_map(lambda a: a[i], cache["layers"])
-                x, nc = block_append(p_l, cfg, x, c_l, cache_len)
+                x, nc = block_append(p_l, cfg, x, c_l, cache_len,
+                                     n_valid=n_valid)
                 entries.append(nc)
             layers = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *entries)
         x = rmsnorm_apply(params["final_norm"], x, cfg.rms_eps)
         logits = self._logits(params, x)
         return logits, {"layers": layers, "len": cache_len + tokens.shape[1]}
 
-    def _hybrid_append(self, params, x, cache):
+    def _hybrid_append(self, params, x, cache, n_valid=None):
         cfg = self.cfg
         k = cfg.attn_every
         ng = cfg.n_layers // k
@@ -538,7 +709,8 @@ class LM:
             x = x + mlp_apply(shared["mlp"], cfg,
                               rmsnorm_apply(shared["ln2"], x, cfg.rms_eps))
             x, states = jax.lax.scan(
-                lambda c2, pc: block_append(pc[0], cfg, c2, pc[1], cache_len),
+                lambda c2, pc: block_append(pc[0], cfg, c2, pc[1], cache_len,
+                                            n_valid=n_valid),
                 x, (p_g, c_g["mamba"]))
             return x, {"attn": (ck, cv), "mamba": states}
 
@@ -566,7 +738,8 @@ class LM:
                         (lead, batch_size, 1, 1, qk_spike_state_width(cfg)),
                         jnp.int32)
                     return (words, empty)
-                return (empty, empty)
+                # two buffers, not one twice: the engine donates the pool
+                return (empty, jnp.zeros_like(empty))
             shp = (lead, batch_size, max_len, hkv, dh)
             return (jnp.zeros(shp, kv_dtype), jnp.zeros(shp, kv_dtype))
 
@@ -575,7 +748,19 @@ class LM:
             return jax.tree_util.tree_map(
                 lambda a: jnp.zeros((lead, *a.shape), a.dtype), st)
 
-        if cfg.family in ("dense", "moe", "vlm"):
+        if cfg.layer_types:
+            # two kinds of state side by side: SSM and conv state per Mamba
+            # layer (O(1) in length), a KV cache per attention layer, in
+            # ``_pattern``'s layout
+            n_per = cfg.n_layers // cfg.layer_period
+            entry = {"mamba": mamba_entry, "attn": attn_entry}
+            layers: dict = {}
+            for key, _, _, n in _pattern_runs(cfg):
+                layers.setdefault(key, []).append(jax.tree_util.tree_map(
+                    lambda a: a.reshape(n_per, n, *a.shape[1:]),
+                    entry[key](n_per * n)))
+            layers = {k: tuple(v) for k, v in layers.items()}
+        elif cfg.family in ("dense", "moe", "vlm"):
             layers = attn_entry(cfg.n_layers)
         elif cfg.family == "ssm":
             layers = mamba_entry(cfg.n_layers)

@@ -12,6 +12,12 @@ Sharding: batch on ('pod','data'); the d_inner axis (and thus heads) on
 'model'; the recurrent state [B, H, P, N] shards the same way. The
 inter-chunk scan is sequential in time but involves no collectives.
 
+Right pads are inert (``n_valid``): at a pad position the step size is 0,
+so exp(dt*A) = 1 and the input term dt*x*B is 0, and the conv state is
+taken from the last K-1 valid inputs. A right-padded prefill then leaves
+the same state as one at the true length, and the serving engine can
+prefill SSM and hybrid prompts in fixed-length buckets.
+
 Spiking hook (paper C3): ``spiking`` replaces the SiLU on the conv branch
 with a LIF spike, making xBC a binary event stream (the SSM input events).
 """
@@ -150,8 +156,13 @@ def _ssd_chunked(xs: Array, dt: Array, A: Array, Bm: Array, Cm: Array,
 def mamba_apply(p: dict, cfg: ModelConfig, x: Array,
                 d_model: Optional[int] = None,
                 init_state: Optional[dict] = None,
-                return_state: bool = False):
-    """Full-sequence forward. x: [B,S,D] -> y: [B,S,D] (+ state dict)."""
+                return_state: bool = False,
+                n_valid: Optional[Array] = None):
+    """Full-sequence forward. x: [B,S,D] -> y: [B,S,D] (+ state dict).
+
+    ``n_valid`` (scalar or [B]): positions from ``n_valid`` on are right
+    pads, which leave the state as they found it; their outputs are
+    undefined. None: every position is valid."""
     d, d_inner, h, g, n, conv_dim = ssm_dims(cfg, d_model)
     b, s, _ = x.shape
     zxbcdt = dense_apply(p["in_proj"], x)
@@ -164,6 +175,10 @@ def mamba_apply(p: dict, cfg: ModelConfig, x: Array,
     Bm = Bm.reshape(b, s, g, n)
     Cm = Cm.reshape(b, s, g, n)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
+    if n_valid is not None:
+        lens = jnp.broadcast_to(jnp.reshape(n_valid, (-1,)), (b,))
+        pad = jnp.arange(s)[None, :] >= lens[:, None]           # [B, S]
+        dt = jnp.where(pad[..., None], 0.0, dt)
     A = -jnp.exp(p["A_log"])
 
     chunk = min(cfg.ssm_chunk, s)
@@ -187,12 +202,17 @@ def mamba_apply(p: dict, cfg: ModelConfig, x: Array,
     out = dense_apply(p["out_proj"], y)
     if not return_state:
         return out
-    # conv state = last K-1 PRE-conv inputs; history (the incoming conv tail
-    # or zeros at sequence start) covers chunks shorter than K-1
+    # conv state = last K-1 valid PRE-conv inputs; history (the incoming
+    # conv tail or zeros at sequence start) covers chunks shorter than K-1
     k1 = cfg.ssm_conv - 1
     hist = (jnp.zeros((b, k1, xbc_raw.shape[-1]), x.dtype)
             if conv_tail is None else conv_tail.astype(x.dtype))
-    tail = jnp.concatenate([hist, xbc_raw], axis=1)[:, -k1:, :]
+    seq = jnp.concatenate([hist, xbc_raw], axis=1)            # [B, K-1+S, C]
+    if n_valid is None:
+        tail = seq[:, -k1:, :]
+    else:
+        rows = lens[:, None] + jnp.arange(k1)[None, :]        # [B, K-1]
+        tail = jnp.take_along_axis(seq, rows[..., None], axis=1)
     return out, {"ssm": final.astype(jnp.float32), "conv": tail}
 
 

@@ -7,7 +7,11 @@ Design (vLLM-style, TPU-static-shapes edition):
     shape [L, max_slots, max_len, Hkv, Dh] — slots are rows of the batch dim.
   * prefill runs per-request (padded to ``prefill_pad`` buckets so a handful
     of compiled shapes serve all prompt lengths) and WRITES the produced
-    cache into the slot row.
+    cache into the slot row. Right pads are inert in every family: causal
+    masking hides them from attention, and the model is told each prompt's
+    true length so they leave SSM state alone. A hybrid slot holds both
+    kinds of state side by side: SSM and conv state per Mamba layer, a KV
+    row per attention layer.
   * decode is ONE jitted step over the whole pool every tick regardless of
     how many slots are live (static shape — idle slots compute garbage that
     is masked out; this is the standard TPU trade).
@@ -21,11 +25,13 @@ Elastic-FIFO pipeline (``prefill_chunk > 0``), mirroring the paper's FIFO
 depth elasticity in software:
   * chunked prefill — each prompt is split into ``prefill_chunk``-token
     chunks that run through ``LM.prefill_chunk`` against a per-request
-    bucket cache; at most ``prefill_chunks_per_tick`` chunks run per engine
-    tick, so one long prompt can no longer freeze every live decode slot
-    (head-of-line stall → bounded p99 decode-tick latency). Bit-identical
-    to the blocking prefill under greedy decode: chunks cover the same
-    padded bucket, so every reduction runs over the same axis lengths.
+    bucket cache (of the pool's ``max_len`` for models with SSM state, so
+    one compiled chunk program serves every bucket); at most
+    ``prefill_chunks_per_tick`` chunks run per engine tick, so one long
+    prompt can no longer freeze every live decode slot (head-of-line
+    stall → bounded p99 decode-tick latency). Equal to the blocking
+    prefill under greedy decode: chunks cover the same padded bucket
+    (attention masks any cache rows past it exactly to zero).
     (Caveat: above ``cfg.flash_threshold`` the blocking prefill switches
     to flash accumulation, whose different f32 reduction order chunked
     prefill does not reproduce — raise the threshold for strict parity on
@@ -86,18 +92,63 @@ _JIT_CACHE: dict = {}
 _greedy_rows = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
 
 
+def _path_str(path) -> str:
+    return "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path)
+
+
+def _slot_axis(path_str: str, ndim: int) -> int:
+    """The slot axis of a pool leaf: [.., slots, K-1, C] for conv state,
+    [.., slots, H, P, N] for SSM state, [.., slots, max_len, H, D] for KV
+    and packed spike words."""
+    return ndim - 3 if "conv" in path_str else ndim - 4
+
+
+def _write_rows_fn(layers, new, slot):
+    """Every pool leaf with ``new``'s rows written at ``slot`` (KV rows
+    from position 0; a qk_spiking KV of length 0 stays as it is)."""
+    def write(path, pool, rows):
+        if rows.size == 0:
+            return pool
+        start = [0] * pool.ndim
+        start[_slot_axis(_path_str(path), pool.ndim)] = slot
+        return jax.lax.dynamic_update_slice(pool, rows.astype(pool.dtype),
+                                            start)
+    return jax.tree_util.tree_map_with_path(write, layers, new)
+
+
+# the pool is donated: the write lands in place, with no second pool
+_write_rows = jax.jit(_write_rows_fn, donate_argnums=0)
+
+
 def _jitted_steps(model):
     key = (type(model), model.cfg)
     if key not in _JIT_CACHE:
-        def prefill_fn(params, tokens):
-            return model.prefill(params, {"tokens": tokens},
+        def prefill_fn(params, tokens, lengths):
+            return model.prefill(params, {"tokens": tokens,
+                                          "lengths": lengths},
                                  return_all_logits=True)
 
         chunk_fn = getattr(model, "prefill_chunk", None)
+        # the decode donates the slot pool: it updates the state and KV
+        # rows in place instead of holding a second pool
         _JIT_CACHE[key] = (jax.jit(prefill_fn),
-                           jax.jit(model.decode_step),
+                           jax.jit(model.decode_step, donate_argnums=2),
                            jax.jit(chunk_fn) if chunk_fn else None)
     return _JIT_CACHE[key]
+
+
+def _state_bytes(layers: Any, slots: int) -> dict:
+    """Bytes one slot holds in the pool, by kind of state: ``ssm`` and
+    ``conv`` (Mamba layers), ``kv`` (attention rows over ``max_len``),
+    ``spike`` (packed qk_spiking state words)."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(layers)[0]:
+        ps = _path_str(path)
+        kind = ("ssm" if "ssm" in ps else "conv" if "conv" in ps
+                else "spike" if leaf.dtype == jnp.int32 else "kv")
+        out[kind] = out.get(kind, 0) + leaf.size * leaf.dtype.itemsize // slots
+    return out
 
 
 class QueueFull(RuntimeError):
@@ -169,7 +220,8 @@ class _PrefillJob:
     """One request's in-flight chunked prefill (an elastic-FIFO entry)."""
     req: Request
     slot: int
-    cache: dict                         # per-request bucket cache
+    cache: Optional[dict]               # per-request cache, made at the
+    # job's first chunk, so only the head of the FIFO holds one
     bucket: int                         # positions this job must process
     done: int = 0                       # positions processed so far
     last_logits: Optional[Array] = None  # logits at the prompt's last token
@@ -274,10 +326,12 @@ class Engine:
         self._out_fifo_hwm = 0
         self._stall_ticks = 0
         self._prefill_chunks = 0
+        self._pad_tokens = 0                # pad positions prefill masked
         # tick log, one record per step() call (see ``step``); a rolling
         # window, so stats() percentiles stay O(window) and memory bounded
         self._ticks: deque = deque(maxlen=4096)
         self._rec: dict = {}                # the record of the running step
+        self._nested: list = []             # ns of phases inside each open one
         self._greedy = None                 # (pool logits, their greedy tokens)
         self._syncs = 0                     # device->host syncs ever made
         # self-healing state + counters
@@ -296,6 +350,7 @@ class Engine:
         self.cache["len"] = jnp.zeros((), jnp.int32)  # engine manages length
         self.slot_len = np.zeros(cfg.max_slots, np.int64)
         self.free_slots = list(range(cfg.max_slots))
+        self.state_bytes = _state_bytes(self.cache["layers"], cfg.max_slots)
 
         if cfg.prefill_chunk > 0 and not hasattr(self.model, "prefill_chunk"):
             raise ValueError(
@@ -433,11 +488,6 @@ class Engine:
 
     # ------------------------------------------------------------- admission
     def _bucket_len(self, s: int) -> int:
-        if self.model.cfg.family in ("ssm", "hybrid"):
-            # SSM recurrences integrate pad positions into the state —
-            # prefill at TRUE length (attention pads are causal-inert,
-            # SSM pads are not)
-            return s
         return min(self.cfg.max_len,
                    -(-s // self.cfg.prefill_pad) * self.cfg.prefill_pad)
 
@@ -459,15 +509,29 @@ class Engine:
         s = len(req.prompt)
         pad_len = self._bucket_len(s)
         toks = np.zeros((1, pad_len), np.int32)
-        toks[0, :s] = req.prompt        # right-pad (causal: pads inert)
+        toks[0, :s] = req.prompt        # right-pad (inert: see the top)
+        self._pad_tokens += pad_len - s
+        self._rec["pad_tokens"] += pad_len - s
         with self._phase("prefill", uid=req.uid, chunk=0):
-            logits, cache = self._prefill(self.params, jnp.asarray(toks))
+            logits, cache = self._prefill(self.params, jnp.asarray(toks),
+                                          np.full((1,), s, np.int32))
             self._write_slot(slot, cache)
             self._activate(req, slot, logits[0, s - 1])
 
     def _admit_chunked(self, req: Request, slot: int) -> None:
-        s = len(req.prompt)
-        bucket = self._bucket_len(s)
+        bucket = self._bucket_len(len(req.prompt))
+        self.prefill_fifo.append(_PrefillJob(req, slot, None, bucket))
+        self._prefill_fifo_hwm = max(self._prefill_fifo_hwm,
+                                     len(self.prefill_fifo))
+
+    def _request_cache(self, bucket: int) -> dict:
+        """An empty cache for one request's chunked prefill, of the bucket's
+        length. A model with SSM state takes the pool's length instead, so
+        one chunk program serves every bucket: its few attention layers (if
+        any) pay for the longer rows, where a compiled chunk per bucket of a
+        deep Mamba stack would lengthen set-up."""
+        if self.model.cfg.family in ("ssm", "hybrid"):
+            bucket = self.cfg.max_len
         cache = self.model.init_cache(1, bucket)
         cache["len"] = jnp.zeros((), jnp.int32)
         if self.model.cfg.kv_dtype:
@@ -479,9 +543,7 @@ class Engine:
             cache["layers"] = jax.tree_util.tree_map(
                 lambda a: a.astype(dt) if a.dtype == jnp.float8_e4m3fn
                 else a, cache["layers"])
-        self.prefill_fifo.append(_PrefillJob(req, slot, cache, bucket))
-        self._prefill_fifo_hwm = max(self._prefill_fifo_hwm,
-                                     len(self.prefill_fifo))
+        return cache
 
     def _chunk_size(self) -> int:
         align = self.model.cfg.prefill_chunk_align
@@ -491,13 +553,17 @@ class Engine:
         """Run ONE chunk of one request's prefill. Returns True when the
         job completed (its slot cache is written and the request is live)."""
         req, s = job.req, len(job.req.prompt)
+        if job.cache is None:
+            job.cache = self._request_cache(job.bucket)
         chunk = min(self._chunk_size(), job.bucket - job.done)
         toks = np.zeros((1, chunk), np.int32)
         valid = max(0, min(chunk, s - job.done))
         toks[0, :valid] = req.prompt[job.done:job.done + valid]
-        logits, job.cache = self._prefill_chunk(self.params,
-                                                jnp.asarray(toks), job.cache)
+        logits, job.cache = self._prefill_chunk(
+            self.params, jnp.asarray(toks), job.cache, np.int32(valid))
         self._prefill_chunks += 1
+        self._pad_tokens += chunk - valid
+        self._rec["pad_tokens"] += chunk - valid
         if job.done <= s - 1 < job.done + chunk:
             job.last_logits = logits[0, s - 1 - job.done]
         job.done += chunk
@@ -531,60 +597,36 @@ class Engine:
 
     # ---------------------------------------------------------- cache moves
     def _write_slot(self, slot: int, prefill_cache: dict) -> None:
-        """Copy one request's prefill cache into its slot row."""
+        """Copy one request's prefill cache into its slot row: every kind
+        of state the model keeps (SSM, conv, KV, packed spike words)."""
+        with self._phase("write_slot"):
+            self.cache["layers"] = _write_rows(
+                self.cache["layers"], prefill_cache["layers"],
+                np.int32(slot))
 
-        def write(path, pool, new):
-            ps = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                          for k in path)
-            nd = pool.ndim
-            idx = [slice(None)] * nd
-            if "ssm" in ps:                 # [.., slots, H, P, N]
-                idx[nd - 4] = slice(slot, slot + 1)
-            elif "conv" in ps:              # [.., slots, K-1, C]
-                idx[nd - 3] = slice(slot, slot + 1)
-            else:                           # KV [.., slots, max_len, H, D]
-                if new.shape[nd - 3] == 0:  # qk_spiking: stateless
-                    return pool
-                idx[nd - 4] = slice(slot, slot + 1)
-                idx[nd - 3] = slice(0, new.shape[nd - 3])
-            return pool.at[tuple(idx)].set(new.astype(pool.dtype))
+    def _slot_rows(self, slot: int) -> Any:
+        """A copy of one slot's rows of every pool (a stalled slot's
+        pre-decode state; the decode donates the pool it reads)."""
+        def take(path, pool):
+            return jax.lax.dynamic_slice_in_dim(
+                pool, slot, 1, _slot_axis(_path_str(path), pool.ndim))
+        return jax.tree_util.tree_map_with_path(take, self.cache["layers"])
 
-        self.cache["layers"] = jax.tree_util.tree_map_with_path(
-            write, self.cache["layers"], prefill_cache["layers"])
-
-    def _restore_slot(self, slot: int, prev_layers: Any) -> None:
-        """Copy one slot's rows back from a pre-decode cache snapshot —
-        makes a stalled slot's tick side-effect-free (its SSM/spike state
-        must not advance while the consumer is not draining)."""
-
-        def restore(path, pool, prev):
-            ps = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                          for k in path)
-            nd = pool.ndim
-            idx = [slice(None)] * nd
-            idx[nd - 3 if "conv" in ps else nd - 4] = slice(slot, slot + 1)
-            idx = tuple(idx)
-            return pool.at[idx].set(prev[idx])
-
-        self.cache["layers"] = jax.tree_util.tree_map_with_path(
-            restore, self.cache["layers"], prev_layers)
+    def _restore_slot(self, slot: int, rows: Any) -> None:
+        """Put back one slot's rows taken by ``_slot_rows`` before the
+        decode — makes a stalled slot's tick side-effect-free (its
+        SSM/KV/spike state must not advance while the consumer is not
+        draining)."""
+        self.cache["layers"] = _write_rows(self.cache["layers"], rows,
+                                           np.int32(slot))
 
     def _scrub_slot(self, slot: int) -> None:
         """Zero one slot's rows in every cache pool — quarantine hygiene:
         a poisoned row must not survive into the slot's next occupant
         (prefill only overwrites the prompt's own positions)."""
 
-        def scrub(path, pool):
-            ps = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                          for k in path)
-            nd = pool.ndim
-            idx = [slice(None)] * nd
-            idx[nd - 3 if "conv" in ps else nd - 4] = slice(slot, slot + 1)
-            idx = tuple(idx)
-            return pool.at[idx].set(jnp.zeros_like(pool[idx]))
-
-        self.cache["layers"] = jax.tree_util.tree_map_with_path(
-            scrub, self.cache["layers"])
+        self._restore_slot(slot, jax.tree_util.tree_map(
+            jnp.zeros_like, self._slot_rows(slot)))
 
     # ------------------------------------------------------ fault injection
     def _resolve_fault_slot(self, slot: int) -> Optional[int]:
@@ -637,9 +679,7 @@ class Engine:
         for i, (path, leaf) in enumerate(flat):
             if not (jnp.issubdtype(leaf.dtype, jnp.floating) and leaf.size):
                 continue
-            ps = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                          for k in path)
-            ax = leaf.ndim - (3 if "conv" in ps else 4)
+            ax = _slot_axis(_path_str(path), leaf.ndim)
             if ax < 0 or leaf.shape[ax] != self.cfg.max_slots:
                 continue
             idx = [0] * leaf.ndim
@@ -674,10 +714,7 @@ class Engine:
                 for path, leaf in flat:
                     if not leaf.size:
                         continue
-                    ps = "/".join(str(getattr(k, "key",
-                                              getattr(k, "idx", k)))
-                                  for k in path)
-                    ax = leaf.ndim - (3 if "conv" in ps else 4)
+                    ax = _slot_axis(_path_str(path), leaf.ndim)
                     if ax < 0 or leaf.shape[ax] != nslots:
                         continue
                     if jnp.issubdtype(leaf.dtype, jnp.floating):
@@ -751,15 +788,23 @@ class Engine:
                **meta):
         """Run one phase of the current tick inside a ``serve.<name>``
         profiler span (``meta`` rides on the span) and add its host
-        nanoseconds to the tick's record. While no trace is active a
-        phase costs a few microseconds of host time."""
+        nanoseconds to the tick's record: the time of a phase nested in
+        another counts in the inner one only, so the phases of a tick add
+        up to at most its ``step``. While no trace is active a phase costs
+        a few microseconds of host time."""
         t0 = time.perf_counter_ns()
+        self._nested.append(0)
         try:
             with annotation(SPAN_PREFIX + name, **meta):
                 yield
         finally:
+            dt = time.perf_counter_ns() - t0
+            inner = self._nested.pop()
+            if self._nested:
+                self._nested[-1] += dt
             ph = self._rec["phases"]
-            ph[name] = ph.get(name, 0) + time.perf_counter_ns() - t0
+            ph[name] = ph.get(name, 0) + (dt if name == "step"
+                                          else dt - inner)
 
     @property
     def last_tick(self) -> Optional[dict]:
@@ -796,7 +841,8 @@ class Engine:
         ran at), ``live`` (slots that sampled a token from the decode),
         ``batched`` (those of them whose token came from the decode's one
         batched greedy fetch; ``live - batched`` sampled one slot at a
-        time), ``decoded``, ``chunks`` (prefill chunks run), ``syncs``
+        time), ``decoded``, ``chunks`` (prefill chunks run), ``pad_tokens``
+        (the right-pad positions those prefills ran and masked), ``syncs``
         (device->host syncs: the decode's wait, the batched greedy fetch,
         one per token sampled alone (a temperature slot, a finished
         prefill's first token), one per packed pool that spike telemetry
@@ -808,8 +854,9 @@ class Engine:
         * ``admit``: the deadline sweep and admission (a blocking prefill
           nests its ``prefill`` span here);
         * ``prefill``: one chunk (upload, dispatch, last-logit slice; on
-          the final chunk the slot write and the first token's sample),
-          with the request's ``uid`` and ``chunk`` index as metadata;
+          the final chunk the slot write, in a nested ``write_slot`` span,
+          and the first token's sample), with the request's ``uid`` and
+          ``chunk`` index as metadata;
         * ``decode``: token and length uploads, the pool decode's dispatch
           and the wait for its logits;
         * ``guard``: fault injection and the integrity scan;
@@ -817,8 +864,8 @@ class Engine:
         * ``sample``: stall restore, quarantine, the batched greedy fetch,
           then per slot the sample, emit, finish and slot release."""
         rec = self._rec = {"tick": self._tick, "live": 0, "batched": 0,
-                           "decoded": False, "chunks": 0, "syncs": 0,
-                           "phases": {}}
+                           "decoded": False, "chunks": 0, "pad_tokens": 0,
+                           "syncs": 0, "phases": {}}
         syncs0, chunks0 = self._syncs, self._prefill_chunks
         try:
             with self._phase("step", jax.profiler.StepTraceAnnotation,
@@ -860,7 +907,7 @@ class Engine:
             # per-slot length vector: every slot attends exactly its own
             # prefix
             self.cache["len"] = jnp.asarray(self.slot_len, jnp.int32)
-            prev_layers = self.cache["layers"] if stalled else None
+            prev = {slot: self._slot_rows(slot) for slot in stalled}
             logits, self.cache = self._decode(self.params, jnp.asarray(toks),
                                               self.cache)
             logits = self._sync(logits, fetch=False)
@@ -895,7 +942,7 @@ class Engine:
                     # once the FIFO drains; temperature sampling is only
                     # reproducible up to the shared RNG stream's
                     # consumption order
-                    self._restore_slot(slot, prev_layers)
+                    self._restore_slot(slot, prev[slot])
             for slot in sorted(bad):
                 # quarantine BEFORE sampling: no token leaves a poisoned
                 # slot
@@ -1061,6 +1108,9 @@ class Engine:
                "prefill_mode": ("chunked" if self.cfg.prefill_chunk > 0
                                 else "blocking"),
                "prefill_chunks": self._prefill_chunks,
+               "prefill_pad_tokens": self._pad_tokens,
+               # bytes one slot holds, by kind of state
+               "state_bytes": dict(self.state_bytes),
                "queue_hwm": self._queue_hwm,
                "prefill_fifo_hwm": self._prefill_fifo_hwm,
                "out_fifo_hwm": self._out_fifo_hwm,
