@@ -51,6 +51,26 @@ def mlp_apply(p: dict, cfg: ModelConfig, x: Array) -> Array:
     return dense_apply(p["down"], h)
 
 
+# ----------------------------------------------------------- serving weights
+def ffn_serving_params(p: dict, dtype) -> dict:
+    """A block's params (one block or a layer stack) with the weights of its
+    MLP or MoE in ``dtype``: the forward reads each of them only through
+    ``.astype(x.dtype)``, so casting once gives the same arithmetic as
+    casting on every call. Every other leaf is the same object."""
+    def cast(tree):
+        return jax.tree_util.tree_map(
+            lambda a: a if a.dtype == dtype else a.astype(dtype), tree)
+
+    out = dict(p)
+    if "mlp" in p:
+        out["mlp"] = cast(p["mlp"])
+    if "moe" in p:
+        # all but the router, which router_probs reads in float32
+        out["moe"] = {k: v if k == "router" else cast(v)
+                      for k, v in p["moe"].items()}
+    return out
+
+
 # --------------------------------------------------------------------- MoE
 def moe_init(rng: Array, cfg: ModelConfig) -> dict:
     d, f, e = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.n_experts

@@ -44,7 +44,8 @@ from ..configs.base import ModelConfig, ShapeSpec
 from ..core.w2ttfs import window_counts
 from .attention import (attn_append, attn_apply, attn_decode, attn_init,
                         attn_prefill)
-from .ffn import mlp_apply, mlp_init, moe_apply, moe_init
+from .ffn import (ffn_serving_params, mlp_apply, mlp_init, moe_apply,
+                  moe_init)
 from .layers import (dense_apply, dense_init, embedding_init,
                      embedding_lookup, embedding_logits, maybe_spike,
                      rmsnorm_apply, rmsnorm_init)
@@ -317,6 +318,32 @@ class LM:
             out.setdefault(key, []).append(jax.tree_util.tree_map(
                 lambda a: a.reshape(n_per, n, *a.shape[1:]), stack))
         return {k: tuple(v) for k, v in out.items()}
+
+    def serving_params(self, params: dict) -> dict:
+        """``params`` as the served programs read them: the FFN weights
+        already in ``cfg.dtype``, so no jitted step casts a whole weight
+        stack on every call. The outputs are bit-identical; the caller's
+        tree is not changed, and every leaf but those is the same object.
+
+        Left as they are: the embedding (the tied readout reads it in
+        float32), the norm scales (read in float32), the MoE router (read in
+        float32) and the attention weights (under fused policies the event
+        kernels accumulate in float32 from float32 tiles)."""
+        cfg = self.cfg
+        if cfg.param_dtype == cfg.dtype:
+            return params
+        out = dict(params)
+        blocks = params["blocks"]
+        if cfg.layer_types:
+            out["blocks"] = {k: tuple(ffn_serving_params(r, cfg.dtype)
+                                      for r in runs)
+                             for k, runs in blocks.items()}
+        else:
+            out["blocks"] = ffn_serving_params(blocks, cfg.dtype)
+        if "shared_attn" in params:
+            out["shared_attn"] = ffn_serving_params(params["shared_attn"],
+                                                    cfg.dtype)
+        return out
 
     def _pattern(self, blocks: dict, x: Array, layer, states=None
                  ) -> tuple[Array, dict]:

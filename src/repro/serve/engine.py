@@ -151,6 +151,17 @@ def _state_bytes(layers: Any, slots: int) -> dict:
     return out
 
 
+def _weights_cast(before: Any, after: Any) -> dict:
+    """The leaves ``serving_params`` replaced, and their bytes before and
+    after the cast (every other leaf is the same object)."""
+    pairs = [(a, b) for a, b in zip(jax.tree_util.tree_leaves(before),
+                                    jax.tree_util.tree_leaves(after))
+             if a is not b]
+    return {"leaves": len(pairs),
+            "bytes_before": sum(a.size * a.dtype.itemsize for a, _ in pairs),
+            "bytes_after": sum(b.size * b.dtype.itemsize for _, b in pairs)}
+
+
 class QueueFull(RuntimeError):
     """Raised by ``submit`` when the admission FIFO stays full (non-blocking
     submit, or a blocking submit that exhausted its tick budget)."""
@@ -308,6 +319,11 @@ class Engine:
                 # used for training under its own "reference" policy)
                 self.model = type(model)(ops.with_policy(model.cfg, eff))
             self.policy = eff
+        # the served programs take the FFN weights in the compute dtype,
+        # cast once here rather than inside every decode and chunk
+        serving = getattr(self.model, "serving_params", None)
+        self.params = params if serving is None else serving(params)
+        self.weights_cast = _weights_cast(params, self.params)
         self.queue: deque[Request] = deque()
         self.prefill_fifo: deque[_PrefillJob] = deque()
         self.active: dict[int, Request] = {}
@@ -1111,6 +1127,8 @@ class Engine:
                "prefill_pad_tokens": self._pad_tokens,
                # bytes one slot holds, by kind of state
                "state_bytes": dict(self.state_bytes),
+               # FFN weights cast to the compute dtype at construction
+               "weights_cast": dict(self.weights_cast),
                "queue_hwm": self._queue_hwm,
                "prefill_fifo_hwm": self._prefill_fifo_hwm,
                "out_fifo_hwm": self._out_fifo_hwm,

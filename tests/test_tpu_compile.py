@@ -4,12 +4,15 @@ Each case lowers one kernel through its public wrapper with
 ``interpret=False`` and compiles it with the TPU compiler for a described
 (not attached) ``v5e:2x2`` chip — what Mosaic refuses here it would refuse
 on the chip. Nothing runs. Shapes are qwen3-1.7b's: 1024 tokens, d_model
-2048, d_ff 6144, 16 heads of 128.
+2048, d_ff 6144, 16 heads of 128. The last case compiles the served
+spiking model's whole decode and prefill chunk at those widths.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -140,3 +143,41 @@ def test_flash_attention(one_chip):
     shape = ((1, H, M, DH), jnp.bfloat16)
     _compile(lambda q, k, v: flash_attention(q, k, v, **ON_CHIP), one_chip,
              shape, shape, shape)
+
+
+def _stack_converts(hlo: str, n_layers: int) -> int:
+    """Converts whose result is a whole layer stack of FFN weights."""
+    return len(re.findall(rf"= bf16\[{n_layers},({D},{F}|{F},{D})\]\S* convert\(",
+                          hlo))
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_served_spiking_step_casts_no_ffn_stack(one_chip, monkeypatch, step):
+    """qwen3-1.7b's spiking form whole under ``fused_packed``, as the
+    serving engine runs it: on the stored float32 tree each served program
+    converts the three layer-stacked FFN weights to bfloat16 on every call;
+    on ``serving_params`` it converts none."""
+    from repro import ops
+    from repro.configs import build_model, get_config
+
+    # the kernel wrappers take their TPU branch when the backend says so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = build_model(get_config("qwen3-1.7b", spiking=True,
+                                   attention_kind="qk_spiking",
+                                   policy=ops.FUSED_PACKED))
+    n_layers = model.cfg.n_layers
+    stored = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    served = jax.eval_shape(model.serving_params, stored)
+    slots, width = (64, 1) if step == "decode_step" else (1, 128)
+    cache = jax.eval_shape(lambda: model.init_cache(slots, 256))
+    cache["len"] = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def hlo(tree):
+        args = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            (tree, jax.ShapeDtypeStruct((slots, width), jnp.int32), cache))
+        return jax.jit(getattr(model, step)).lower(*args).compile().as_text()
+
+    assert _stack_converts(hlo(stored), n_layers) == 3
+    assert _stack_converts(hlo(served), n_layers) == 0
